@@ -15,12 +15,13 @@ spans) are merged in and a Chrome trace lands at
 ``benchmarks/report.py --check-regression`` compares the latest
 history entry against the median of the prior runs.
 
-``--compilation-cache DIR`` opts into jax's persistent compilation
-cache for the smoke run: compiled executables land under DIR, so a
-second run with the same DIR skips XLA compilation entirely.  With
-``POND_TRACE=1`` the per-family ``jit.*.lower`` spans quantify the
-cold-vs-warm lowering cost (summed into the ``jit_lower_total_s``
-bench key).
+Every run keeps jax's persistent compilation cache
+(``repro.core.compile_cache``): in ``JAX_COMPILATION_CACHE_DIR`` when
+that is set, else in ``<repo>/.jax_cache``, so a second run skips XLA
+compilation.  With ``POND_TRACE=1`` the per-family ``jit.*.lower``
+spans quantify the cold-vs-warm lowering cost (summed into the
+``jit_lower_total_s`` bench key).  The runner exits non-zero when any
+module raised.
 
 Multi-device keys (``device_*``, ``overlap_ratio``) record the
 trace-axis-sharded stream batch; CPU-only hosts must export
@@ -33,8 +34,11 @@ import argparse
 import importlib
 import json
 import os
+import sys
 import time
 import traceback
+
+from repro.core import compile_cache
 
 MODULES = [
     "benchmarks.azure_e2e",
@@ -82,17 +86,7 @@ def _fail_family_probe():
             "reject_rates": [round(float(x), 6) for x in r.reject_rate]}
 
 
-def _enable_compilation_cache(cache_dir: str) -> None:
-    """Opt into jax's persistent compilation cache (all entries, no
-    minimum compile time) — must run before anything jits."""
-    import jax
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
-
-def perf_smoke(cache_dir: str | None = None):
+def perf_smoke(cache_dir: str):
     """Time the fig3 quick path; emit experiments/BENCH_replay.json.
 
     Alongside the single-trace engine numbers this records the
@@ -139,8 +133,6 @@ def perf_smoke(cache_dir: str | None = None):
     ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` first on
     CPU-only hosts or the stage records itself skipped.
     """
-    if cache_dir is not None:
-        _enable_compilation_cache(cache_dir)
     from benchmarks import (azure_e2e, fig3_poolsize, fig17_sensitivity,
                             fig_topology, latency_bench)
     from repro.core import obs
@@ -264,24 +256,20 @@ def perf_smoke(cache_dir: str | None = None):
     bench["timestamp"] = manifest["timestamp"]
     bench["manifest"] = manifest
     bench["compilation_cache_dir"] = cache_dir
-    if cache_dir is not None:
-        bench["compilation_cache_entries"] = len(os.listdir(cache_dir))
+    bench["compilation_cache_entries"] = len(os.listdir(cache_dir))
     if rec.enabled:
         bench["obs"] = rec.metrics()
-        # cold-vs-warm lowering cost: with --compilation-cache, a warm
-        # rerun against the same dir drives this toward zero
+        # cold-vs-warm lowering cost: a warm rerun against the same
+        # cache dir drives this toward zero
         bench["jit_lower_total_s"] = round(sum(
             v for k, v in bench["obs"].items()
             if k.startswith("span.jit.") and k.endswith(".lower.total_s")
         ), 3)
-    if cache_dir is not None:
-        lower = bench.get("jit_lower_total_s")
-        print(f"  compilation cache: "
-              f"{bench['compilation_cache_entries']} entries at "
-              f"{cache_dir}"
-              + (f", jit lowering {lower}s this run" if lower is not None
-                 else "")
-              + " — rerun with the same dir to measure warm lowering")
+    lower = bench.get("jit_lower_total_s")
+    print(f"  compilation cache: "
+          f"{bench['compilation_cache_entries']} entries at {cache_dir}"
+          + (f", jit lowering {lower}s this run" if lower is not None
+             else ""))
     os.makedirs("experiments", exist_ok=True)
     with open("experiments/BENCH_replay.json", "w") as f:
         json.dump(bench, f, indent=1)
@@ -325,18 +313,14 @@ def main(argv=None):
     ap.add_argument("--perf-smoke", action="store_true",
                     help="time the fig3 quick replay path and emit "
                          "experiments/BENCH_replay.json")
-    ap.add_argument("--compilation-cache", default=None, metavar="DIR",
-                    help="persist jax-compiled executables under DIR "
-                         "(opt-in; a second --perf-smoke run with the "
-                         "same DIR skips XLA compilation)")
     args = ap.parse_args(argv)
+    cache_dir = compile_cache.enable()
     if args.perf_smoke:
-        perf_smoke(cache_dir=args.compilation_cache)
+        perf_smoke(cache_dir)
         return
-    if args.compilation_cache:
-        _enable_compilation_cache(args.compilation_cache)
     out = {}
     n_pass = n_fail = 0
+    raised = []
     for name in MODULES:
         if args.only and args.only not in name:
             continue
@@ -346,6 +330,7 @@ def main(argv=None):
             res = mod.run(quick=not args.full)
         except Exception as e:  # noqa: BLE001
             traceback.print_exc()
+            raised.append(name)
             res = {"error": str(e),
                    "claims": [{"claim": f"{name} runs", "ok": False,
                                "detail": str(e)}]}
@@ -364,6 +349,8 @@ def main(argv=None):
         json.dump(out, f, indent=1, default=default)
     print(f"=== paper-claim checks: {n_pass} PASS / {n_fail} FAIL ===")
     print("results -> experiments/benchmarks.json")
+    if raised:
+        sys.exit(f"benchmark modules raised: {', '.join(raised)}")
 
 
 if __name__ == "__main__":

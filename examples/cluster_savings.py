@@ -36,14 +36,15 @@ import time
 
 import numpy as np
 
-from repro.core import cluster_sim, policy_engine, replay_engine, traces
+from repro.core import (cluster_sim, compile_cache, policy_engine,
+                        replay_engine, traces)
 from repro.core.control_plane import ControlPlane, ControlPlaneConfig
 from repro.core.pool_manager import PoolManager
 from repro.core.predictors.models import (LatencySensitivityModel,
                                           UntouchedMemoryModel)
 
 
-def _models(pop, horizon):
+def fit_models(pop, horizon):
     train = pop.sample_vms(1200, horizon, seed=1)
     li = LatencySensitivityModel(pdm=0.05).fit(
         traces.pmu_matrix(train), traces.slowdowns(train, 182))
@@ -92,7 +93,7 @@ def run_policy_grid(spec, vms_list, cfg, pop, horizon):
     taus = tuple(round(float(t), 6) for t in axes.get("tau", [0.05]))
     pdms = tuple(float(p) for p in axes.get("pdm", [0.05]))
     ths = tuple(float(t) for t in axes.get("li", [0.05]))
-    li, _, hist, meta, ut = _models(pop, horizon)
+    li, _, hist, meta, ut = fit_models(pop, horizon)
     um_models = policy_engine.fit_um_grid(meta, ut, taus)
     settings = policy_engine.make_grid(taus=taus, pdms=pdms,
                                        li_thresholds=ths)
@@ -148,6 +149,7 @@ def main(argv=None):
                          "'tau=0.1:0.3:3,pdm=0.02:0.1:3' (axes tau, "
                          "pdm, li; each lo:hi:n or a single value)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     horizon = 5 * 86400
     pop = traces.Population(seed=0)
@@ -239,7 +241,7 @@ def main(argv=None):
                   f"{br[:, j].mean():.4f}±{br[:, j].std():.4f}")
 
     # --- 3. full provisioning searches, engine-backed ------------------
-    li, um, hist, *_ = _models(pop, horizon)
+    li, um, hist, *_ = fit_models(pop, horizon)
     replay_engine.stats_reset()
     cache: dict = {}
     t0 = time.perf_counter()

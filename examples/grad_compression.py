@@ -10,19 +10,20 @@ import jax                                    # noqa: E402
 import jax.numpy as jnp                       # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
+from repro.core import compile_cache          # noqa: E402
 from repro.launch import hlo_analysis         # noqa: E402
 from repro.optim.compress import QTensor      # noqa: E402
 
 
 def main():
+    compile_cache.enable()
     from repro.launch.mesh import make_mesh
-    from repro.sharding.rules import shard_map
     mesh = make_mesh((2, 4), ("pod", "data"))
     g_spec = NamedSharding(mesh, P("data", None))
     grads = jax.ShapeDtypeStruct((1024, 512), jnp.float32)
 
     def sync_fp32(g):
-        return shard_map(
+        return jax.shard_map(
             lambda x: jax.lax.pmean(x, "pod"), mesh=mesh,
             in_specs=P("data", None), out_specs=P("data", None),
             check_vma=False)(g)
@@ -36,7 +37,7 @@ def main():
             scales = jax.lax.all_gather(q.scale, "pod")      # fp32, small
             deq = jnp.mean(datas.astype(jnp.float32) * scales, axis=0)
             return deq.reshape(-1)[: x.size].reshape(x.shape)
-        return shard_map(local, mesh=mesh, in_specs=P("data", None),
+        return jax.shard_map(local, mesh=mesh, in_specs=P("data", None),
                              out_specs=P("data", None),
                              check_vma=False)(g)
 

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import get_smoke
-from repro.core import traces
+from repro.core import compile_cache, traces
 from repro.core.control_plane import ControlPlane, ControlPlaneConfig
 from repro.core.pool_manager import PoolManager
 from repro.data.pipeline import DataConfig, ShardedBatches
@@ -23,6 +23,7 @@ from repro.sharding.rules import ShardCtx
 
 
 def main():
+    compile_cache.enable()
     cfg = get_smoke("qwen2-1.5b")
     model = build_model(cfg)
     print(f"arch={cfg.name}: {cfg.num_layers}L d={cfg.d_model}")

@@ -7,10 +7,12 @@ hundred steps on the synthetic bigram stream, with checkpointing.
 """
 import argparse
 
+from repro.core import compile_cache
 from repro.launch import train as lt
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--ckpt-dir", default="/tmp/pond_train_small")

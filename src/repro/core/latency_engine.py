@@ -68,9 +68,9 @@ def _jnp_x64():
     """jax.numpy + the enable-x64 context: the float grids compare and
     accumulate in float64, matching the numpy oracles bitwise (jax
     defaults to float32 otherwise)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
-    return jnp, enable_x64
+    return jnp, jax.enable_x64
 
 
 # ------------------------------------------------- Fig 7/8 latency model --

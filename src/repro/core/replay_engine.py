@@ -152,6 +152,20 @@ _INF = np.inf
 _I16_SAFE = sweep_core.I16_SAFE   # re-export: boundary tests pin it
 
 
+def _auto_backend(backend: str, exact: bool,
+                  fallback: str = "numpy") -> str:
+    """Resolve ``backend="auto"``: the XLA sweep when jax is importable
+    and every decision is an integral GB (``exact``), else ``fallback``.
+    Each fallback bumps the ``replay.backend_numpy`` counter, so a run
+    that expects the device can see that a sweep never reached it."""
+    if backend != "auto":
+        return backend
+    if exact and sweep_core.jax_importable():
+        return "jax"
+    obs.get_recorder().count("replay.backend_numpy")
+    return fallback
+
+
 # ----------------------------------------------------- decision ingest -----
 def _decision_arrays(decisions, n: int):
     """``(local_gb, pool_gb, t_migrate)`` float64 arrays from either a
@@ -518,9 +532,7 @@ class CompiledReplay:
         pool_gb = np.atleast_1d(np.asarray(pool_gb, float))
         server_gb, pool_gb = np.broadcast_arrays(server_gb, pool_gb)
         t0 = time.perf_counter()
-        if backend == "auto":
-            backend = "jax" if (self._exact and
-                                sweep_core.get_fail_sweep()) else "oracle"
+        backend = _auto_backend(backend, self._exact, "oracle")
         if backend == "jax":
             res = self._availability_jax(server_gb, pool_gb, mitigation,
                                          state_dtype, per_failure)
@@ -830,9 +842,7 @@ class CompiledReplay:
         denom = max(n_vms, 1)
         if not n_ev:
             return np.zeros(n0)
-        if backend == "auto" and self._exact and sweep_core.get_sweep():
-            backend = "jax"
-        if backend == "jax":
+        if _auto_backend(backend, self._exact) == "jax":
             rates = self._reject_rates_jax(server_gb, pool_gb,
                                            state_dtype=state_dtype,
                                            devices=devices)
@@ -1135,10 +1145,7 @@ class CompiledReplay:
         denom = max(self.n_vms, 1)
         if not self.n_events:
             return np.zeros(n0)
-        if backend == "auto":
-            backend = ("jax" if self._exact
-                       and sweep_core.get_pod_sweep() else "numpy")
-        if backend == "jax":
+        if _auto_backend(backend, self._exact) == "jax":
             rates = self._fleet_rates_jax(sgb, caps, topos, state_dtype)
         else:
             ev = self._fleet_events_np()
@@ -1618,6 +1625,14 @@ def _upload_job(build, sharding=None):
     return out, t0, time.perf_counter_ns(), nbytes
 
 
+def _count_out_devices(rec, out) -> None:
+    """``sweep.out_devices.<n>``: one count per candidate chunk whose
+    reject counters came back spread over ``n`` devices — how a run
+    shows that ``devices=`` really partitioned the sweep."""
+    if rec.enabled:
+        rec.count(f"sweep.out_devices.{len(out.devices())}")
+
+
 # --------------------------------------------- divergence windows --
 def _stream_reference(stream):
     """Infinite-capacity reference replay over a stream's shards.
@@ -1643,10 +1658,18 @@ def _stream_reference(stream):
     if not (stream._exact and cps.is_integer()):
         stream._ref = "unusable"
         return None
+    stream._ref = _reference_replay(stream, int(cps))
+    return stream._ref
+
+
+@obs.traced("stream.reference")
+def _reference_replay(stream, cps: int) -> dict:
+    """The replay behind :func:`_stream_reference` (host side, once
+    per stream): per-shard demand maxima plus boundary snapshots."""
     big = 1 << 60
     n_srv = stream.n_servers
     group_of = np.asarray(stream.group_of, np.int64)
-    fc = np.full(n_srv, int(cps), np.int64)
+    fc = np.full(n_srv, cps, np.int64)
     um = np.zeros(n_srv, np.int64)
     up = np.zeros(stream.n_groups, np.int64)
     slots = np.full(stream._n_slots, -1, np.int64)
@@ -1707,9 +1730,7 @@ def _stream_reference(stream):
         max_pool[si] = mp
         snaps.append((fc.copy(), um.copy(), up.copy(), slots.copy(),
                       rej))
-    stream._ref = {"max_srv": max_srv, "max_pool": max_pool,
-                   "snaps": snaps}
-    return stream._ref
+    return {"max_srv": max_srv, "max_pool": max_pool, "snaps": snaps}
 
 
 def _skip_count(ref, min_sgb, min_pgb, n_shards):
@@ -1801,6 +1822,7 @@ class CompiledReplayStream:
     trace files that stream through this path unchanged.
     """
 
+    @obs.traced("stream.compile")
     def __init__(self, vms, decisions=None, cfg=None, *,
                  max_events_per_shard: int = 262_144, decide=None):
         if cfg is None:
@@ -2102,10 +2124,7 @@ class CompiledReplayStream:
         denom = max(self.n_vms, 1)
         if not self.n_events:
             return np.zeros(n0)
-        if backend == "auto":
-            backend = "jax" if (self._exact and sweep_core.get_sweep()) \
-                else "numpy"
-        if backend == "jax":
+        if _auto_backend(backend, self._exact) == "jax":
             rejects, cand_events = self._sweep_jax(
                 server_gb, pool_gb, reject_cap, state_dtype, checkpoint,
                 devices=devices, skip_windows=skip_windows)
@@ -2267,6 +2286,7 @@ class CompiledReplayStream:
                         rec.count("stream.reject_cap_exits")
                         break                   # every candidate decided
             rejects[lo:hi] = np.asarray(carry[4])[:k]
+            _count_out_devices(rec, carry[4])
         if io is not None:
             io.done()
         return rejects, cand_events
@@ -2349,10 +2369,7 @@ class CompiledReplayStream:
         denom = max(self.n_vms, 1)
         if not self.n_events:
             return np.zeros(n0)
-        if backend == "auto":
-            backend = ("jax" if self._exact
-                       and sweep_core.get_pod_sweep() else "numpy")
-        if backend == "jax":
+        if _auto_backend(backend, self._exact) == "jax":
             rejects, cand_events = self._fleet_sweep_jax(
                 sgb, caps, topos, reject_cap, state_dtype)
         else:
@@ -2617,9 +2634,7 @@ class CompiledReplayBatch:
         server_gb, pool_gb = _broadcast_candidates(self.k, server_gb,
                                                    pool_gb)
         n0 = server_gb.shape[1]
-        if backend == "auto" and self._exact and \
-                sweep_core.get_sweep(batched=True):
-            backend = "jax"
+        backend = _auto_backend(backend, self._exact)
         if backend != "jax":
             return np.stack([
                 eng.reject_rates(server_gb[i], pool_gb[i],
@@ -2721,9 +2736,7 @@ class CompiledReplayBatch:
                 f"topology covers {topos[0].n_servers} servers; batch "
                 f"has {self.n_servers}")
         n0 = len(sgb)
-        if backend == "auto" and self._exact and \
-                sweep_core.get_pod_sweep(batched=True):
-            backend = "jax"
+        backend = _auto_backend(backend, self._exact)
         if backend != "jax":
             # trim the dense capacity rows back to each lane's pod count
             per_lane = [caps[i, :t.n_pods] for i, t in enumerate(topos)]
@@ -2845,9 +2858,7 @@ class CompiledReplayBatch:
         server_gb, pool_gb = _broadcast_candidates(self.k, server_gb,
                                                    pool_gb)
         n0 = server_gb.shape[1]
-        if backend == "auto":
-            backend = "jax" if (self._exact and
-                                sweep_core.get_fail_sweep()) else "oracle"
+        backend = _auto_backend(backend, self._exact, "oracle")
         t0 = time.perf_counter()
         if backend != "jax":
             per = [eng.availability(server_gb[i], pool_gb[i], mitigation,
@@ -2936,7 +2947,8 @@ class CompiledReplayStreamBatch:
     neighbors (``tests/test_replay_stream.py`` asserts this on the
     fixture and a 100k-VM trace, both backends and both state dtypes).
     The carry is placed with ``jax.device_put`` and donated back to the
-    sweep, so it stays device-resident across shards (GPU/TPU-ready).
+    sweep, so it stays device-resident across shards (``chip_smoke.py``
+    runs this path on a TPU).
 
     Usage (K seeds past the monolithic memory ceiling)::
 
@@ -3076,9 +3088,7 @@ class CompiledReplayStreamBatch:
         n0 = server_gb.shape[1]
         if not self.n_shards:
             return np.zeros((self.k, n0))
-        if backend == "auto":
-            backend = "jax" if (self._exact and sweep_core.get_sweep()) \
-                else "numpy"
+        backend = _auto_backend(backend, self._exact)
         if backend != "jax":
             return np.stack([
                 s.reject_rates(server_gb[i], pool_gb[i],
@@ -3224,6 +3234,7 @@ class CompiledReplayStreamBatch:
                         rec.count("stream.reject_cap_exits")
                         break               # every lane decided
             rejects[:, lo:hi] = np.asarray(carry[4])[:self.k, :kc]
+            _count_out_devices(rec, carry[4])
         if io is not None:
             io.done()
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
@@ -3261,9 +3272,7 @@ class CompiledReplayStreamBatch:
         n0 = len(sgb)
         if not self.n_shards:
             return np.zeros((self.k, n0))
-        if backend == "auto":
-            backend = ("jax" if self._exact
-                       and sweep_core.get_pod_sweep() else "numpy")
+        backend = _auto_backend(backend, self._exact)
         if backend != "jax":
             per_lane = [caps[i, :t.n_pods] for i, t in enumerate(topos)]
             return np.stack([

@@ -303,12 +303,9 @@ def get_sweep(state_dtype: str = "int32", *, with_carry: bool = False,
                                 in_axes=((0, 0, 0, 0, 0, 0), None,
                                          None, None, None, None, 0, 0))
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
-                in_specs, out_specs = _plain_shard_specs(
+                base = _shard(base, mesh, _plain_shard_specs(
                     jax.sharding.PartitionSpec, with_carry, batched,
-                    shard_axis)
-                base = shard_map(base, mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+                    shard_axis))
             fn = jax.jit(base, donate_argnums=_CARRY_ARGNUMS
                          if with_carry else ())
         if rec.enabled:
@@ -750,11 +747,8 @@ def get_pod_sweep(state_dtype: str = "int32", *,
                                                None, None, None, None,
                                                None, 0, 0))
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
-                in_specs, out_specs = _pod_shard_specs(
-                    jax.sharding.PartitionSpec, with_carry)
-                base = shard_map(base, mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+                base = _shard(base, mesh, _pod_shard_specs(
+                    jax.sharding.PartitionSpec, with_carry))
             fn = jax.jit(base, donate_argnums=_POD_CARRY_ARGNUMS
                          if with_carry else ())
         if rec.enabled:
@@ -1123,17 +1117,13 @@ _MESHES: dict = {}     # device-id tuple -> cached 1-D "shard"-axis Mesh
 
 
 def make_mesh(shape, axes, devices=None):
-    """``jax.make_mesh`` across jax versions (the single mesh shim —
-    ``launch/mesh.py`` re-exports it): ``AxisType`` only exists on
-    jax >= 0.5 (where Auto is the default anyway).  ``devices`` narrows
-    the mesh to an explicit device list (default: all visible)."""
+    """``jax.make_mesh`` with Auto axis types (``launch/mesh.py``
+    re-exports it).  ``devices`` narrows the mesh to an explicit device
+    list (default: all visible)."""
     import jax
-    kw = {} if devices is None else {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes), **kw)
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def resolve_devices(devices):
@@ -1195,6 +1185,16 @@ def named_sharding(mesh, *spec):
 
 def _mesh_key(mesh):
     return tuple(d.id for d in mesh.devices.flat)
+
+
+def _shard(fn, mesh, specs):
+    """``fn`` wrapped in ``jax.shard_map`` over ``mesh`` with the
+    ``(in_specs, out_specs)`` pair ``specs``.  The partitioned rows and
+    lanes replay independently, so there is nothing to replicate-check."""
+    import jax
+    in_specs, out_specs = specs
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _plain_shard_specs(P, with_carry: bool, batched: bool, axis: str):

@@ -4,7 +4,7 @@ Single pod = 16x16 (256 chips, v5e-256 topology); multi-pod = 2 pods x 256.
 A function (not a module-level constant) so importing never touches jax
 device state — the dry-run must set XLA_FLAGS before first jax init.
 
-The version-portable ``make_mesh`` shim lives in ``core/sweep_core.py``
+The ``make_mesh`` helper lives in ``core/sweep_core.py``
 (the sharded sweep engine needs it too); this module re-exports it so
 launch-side callers keep a single import point.
 """

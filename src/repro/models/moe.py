@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
-from repro.sharding.rules import shard_map
 from repro.models.params import ParamSpec
 
 
@@ -182,7 +181,7 @@ def moe_sharded(p: dict, x: jax.Array, cfg: ArchConfig, ctx,
         y = jax.lax.psum(y, ctx.model_axis)
         return y.astype(xl.dtype).reshape(bl, sl, d)
 
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=(batch_spec, P(None, None), P(ctx.model_axis, None, None),
                   P(ctx.model_axis, None, None), P(ctx.model_axis, None, None)),
@@ -263,7 +262,7 @@ def moe_sharded_2d(p: dict, x: jax.Array, cfg: ArchConfig, ctx,
         y = jax.lax.psum(y, ctx.model_axis)      # expert groups
         return y.astype(xl.dtype).reshape(bl, s, d)
 
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=(batch_spec, P(None, None),
                   P(ctx.model_axis, None, da),
@@ -349,7 +348,7 @@ def moe_sharded_a2a(p: dict, x: jax.Array, cfg: ArchConfig, ctx,
         y = jnp.zeros((t, d), jnp.float32).at[tok_of].add(contrib)
         return y.astype(xl.dtype).reshape(bl, sl, d)
 
-    y = shard_map(
+    y = jax.shard_map(
         local_fn, mesh=ctx.mesh,
         in_specs=(batch_spec, P(None, None),
                   P((da, ma), None, None), P((da, ma), None, None),

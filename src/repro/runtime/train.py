@@ -28,8 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.compute import einsum_f32
 from repro.optim import adamw
-from repro.sharding.rules import (ShardCtx, default_rules, partition_tree,
-                                 shard_map)
+from repro.sharding.rules import ShardCtx, default_rules, partition_tree
 
 MTP_WEIGHT = 0.3
 
@@ -112,7 +111,7 @@ def chunked_xent(hidden, w, labels, chunk: int = 512,
             return (jax.lax.psum(tot, ma),
                     jax.lax.psum(cnt, ma))
 
-        total, count = shard_map(
+        total, count = jax.shard_map(
             local, mesh=ctx.mesh,
             in_specs=(P(None, None, ma, None), P(None, None, ma),
                       P(None, None)),

@@ -24,20 +24,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.params import ParamSpec, tree_map_specs
 
-# jax >= 0.5 promotes shard_map to the top level and renames check_rep ->
-# check_vma; keep one shim so model code runs on either API.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                               # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map_04(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-
-
 @dataclasses.dataclass(frozen=True)
 class ShardCtx:
     """Threaded through model forward fns; None mesh = single-device."""

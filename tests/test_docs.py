@@ -39,7 +39,7 @@ def test_readme_covers_streaming_scale_out():
                   "devices=", "shard_map",
                   "--xla_force_host_platform_device_count",
                   "overlap_ratio", "skip_windows", "--what device",
-                  "--compilation-cache"):
+                  "JAX_COMPILATION_CACHE_DIR"):
         assert topic in text, f"README misses {topic!r}"
     # measured streaming numbers stay cited (events/s at K seeds x
     # N shards come from the perf-smoke artifact)

@@ -164,6 +164,34 @@ def test_tracing_identity_of_results():
     assert rec.metrics()["span.replay.reject_rates.count"] == 1
 
 
+@pytest.mark.skipif(not HAS_JAX, reason="needs jax")
+@pytest.mark.parametrize("engine", ["replay", "stream"])
+def test_auto_backend_numpy_fallback_is_counted(engine):
+    """``backend="auto"`` counts every fallback to the numpy sweep
+    (non-integral decisions) and nothing when the XLA sweep runs."""
+    from benchmarks import common
+    cfg = cluster_sim.ClusterConfig(n_servers=8, pool_sockets=8)
+    vms = common.population().sample_vms(200, 86400.0, seed=4)
+    halves = [cluster_sim.VMDecision(vm.mem_gb - 0.5, 0.5, False, None)
+              for vm in vms]
+    whole, _ = cluster_sim.policy_decisions(vms, "static")
+
+    def build(dec):
+        if engine == "stream":
+            return replay_engine.CompiledReplayStream(
+                vms, dec, cfg, max_events_per_shard=256)
+        return replay_engine.CompiledReplay(vms, dec, cfg)
+
+    counts = []
+    for dec, backend in ((whole, "auto"), (halves, "auto"),
+                         (halves, "numpy")):
+        rec = obs.Recorder()
+        with obs.use_recorder(rec):
+            build(dec).reject_rates([300.0], [64.0], backend=backend)
+        counts.append(rec.metrics().get("replay.backend_numpy", 0))
+    assert counts == [0, 1, 0]
+
+
 # --------------------------------------------------- jit-cache counters ---
 @pytest.mark.skipif(not HAS_JAX, reason="needs jax")
 @pytest.mark.parametrize("state_dtype,batched", [
