@@ -12,19 +12,10 @@ numbers from the same artifact.
 Observability additions (``core/obs.py``):
 
   PYTHONPATH=src python -m benchmarks.report --what obs
-  PYTHONPATH=src python -m benchmarks.report --what replay --history
-  PYTHONPATH=src python -m benchmarks.report --check-regression
 
 ``--what obs`` renders the engine counter table (jit-cache hits vs
 misses, padding waste, span timings) recorded by a ``POND_TRACE=1``
-perf-smoke run; ``--history`` prints a metric's trajectory over the
-last N runs from ``experiments/BENCH_history.jsonl``;
-``--check-regression`` compares the latest history entry against the
-median of the prior runs and WARNS on >25% slowdowns; by default it
-always exits 0 (CI wires it as a warn-only step — shared-runner
-timings are noisy), while ``--fail-on-regression`` makes warnings
-exit 1 for runs that want a hard gate (CI exposes this as a manual
-workflow-dispatch input).
+perf-smoke run.
 
 ``--what device`` renders the multi-device sharding table
 (``device_*``/``overlap_ratio`` keys from a perf-smoke run with
@@ -36,29 +27,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import statistics
-
-HISTORY_PATH = "experiments/BENCH_history.jsonl"
-
-#: perf metrics tracked by --history / --check-regression, grouped by
-#: table: (bench key, direction) — "lower" means lower is better
-#: (wall seconds), "higher" means higher is better (throughput,
-#: speedups).  Regressions are flagged relative to the direction.
-PERF_METRICS = {
-    "replay": [("wall_s", "lower"), ("events_per_sec", "higher"),
-               ("batched_events_per_sec", "higher"),
-               ("streaming_events_per_sec", "higher"),
-               ("stream_batch_events_per_sec", "higher")],
-    "policy": [("policy_compiled_s", "lower"),
-               ("policy_vms_per_sec", "higher")],
-    "latency": [("latency_wall_s", "lower"),
-                ("latency_min_speedup_vs_scalar", "higher")],
-    "topology": [("topology_compiled_s", "lower"),
-                 ("topology_speedup_vs_oracle", "higher")],
-    "device": [("device_stream_batch_events_per_sec", "higher"),
-               ("device_speedup_vs_single", "higher"),
-               ("overlap_ratio", "higher")],
-}
 
 
 def _load(outdir, mesh):
@@ -331,93 +299,6 @@ def obs_table(path: str = "experiments/BENCH_replay.json") -> str:
     return head + "\n\n" + "\n".join(lines)
 
 
-def load_history(path: str = HISTORY_PATH) -> list:
-    """BENCH_history.jsonl entries, oldest first; torn/garbled lines
-    (a killed run mid-append) are skipped, not fatal."""
-    entries = []
-    if not os.path.isfile(path):
-        return entries
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                entries.append(json.loads(line))
-            except json.JSONDecodeError:
-                continue
-    return entries
-
-
-def history_table(what: str, last: int = 10,
-                  path: str = HISTORY_PATH) -> str:
-    """Trajectory of one table's perf metrics over the last N
-    perf-smoke runs (newest last) — regressions visible without
-    re-running anything."""
-    metrics = PERF_METRICS.get(what)
-    if metrics is None:
-        return f"(no history metrics defined for --what {what})"
-    keys = [k for k, _ in metrics]
-    lines = ["| timestamp | sha | backend | " + " | ".join(keys) + " |",
-             "|---" * (3 + len(keys)) + "|"]
-    entries = load_history(path)
-    if not entries:
-        lines.append("| (no history yet — run `python -m benchmarks.run "
-                     "--perf-smoke`) |" + " — |" * (2 + len(keys)))
-        return "\n".join(lines)
-    for e in entries[-last:]:
-        man, bench = e.get("manifest", {}), e.get("bench", {})
-        row = [str(man.get("timestamp", "?")),
-               str(man.get("git_sha", "?"))[:9],
-               str(man.get("backend", "?"))]
-        row += [str(bench.get(k, "—")) for k in keys]
-        lines.append("| " + " | ".join(row) + " |")
-    return "\n".join(lines)
-
-
-def check_regression(path: str = HISTORY_PATH,
-                     threshold: float = 0.25) -> list:
-    """Compare the latest history entry against the median of the
-    prior runs; returns WARN strings for metrics that regressed by
-    more than ``threshold``.  Warn-only by design: the caller (CI)
-    never fails on these — timings on shared runners are noisy, and
-    the first history entry has nothing to compare against.
-    """
-    entries = load_history(path)
-    if len(entries) < 2:
-        print(f"check-regression: {len(entries)} history "
-              f"{'entry' if len(entries) == 1 else 'entries'} in "
-              f"{path} — need >= 2 to compare, skipping")
-        return []
-    latest = entries[-1].get("bench", {})
-    prior = [e.get("bench", {}) for e in entries[:-1]]
-    warns = []
-    for metrics in PERF_METRICS.values():
-        for key, direction in metrics:
-            cur = latest.get(key)
-            hist = [b.get(key) for b in prior
-                    if isinstance(b.get(key), (int, float))]
-            if not isinstance(cur, (int, float)) or not hist:
-                continue
-            med = statistics.median(hist)
-            if med <= 0 or cur <= 0:
-                continue
-            ratio = cur / med if direction == "lower" else med / cur
-            if ratio > 1.0 + threshold:
-                warns.append(
-                    f"WARN {key}: {cur:g} vs history median {med:g} "
-                    f"over {len(hist)} runs "
-                    f"({(ratio - 1) * 100:.0f}% regression)")
-    for w in warns:
-        print(w)
-    if not warns:
-        print(f"check-regression: latest run within {threshold:.0%} of "
-              f"the history median on all "
-              f"{sum(len(m) for m in PERF_METRICS.values())} tracked "
-              f"metrics ({len(entries)} runs)")
-    return warns
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--outdir", default="experiments/dryrun")
@@ -425,38 +306,7 @@ def main():
                     choices=["all", "dryrun", "roofline", "collectives",
                              "replay", "policy", "latency", "topology",
                              "device", "obs"])
-    ap.add_argument("--history", action="store_true",
-                    help="print the --what table's perf-metric "
-                         "trajectory from experiments/"
-                         "BENCH_history.jsonl instead of the table")
-    ap.add_argument("--last", type=int, default=10,
-                    help="history entries to show (default 10)")
-    ap.add_argument("--check-regression", action="store_true",
-                    help="compare the latest BENCH_history.jsonl entry "
-                         "against the history median; WARN on >25%% "
-                         "slowdowns (exits 0 unless "
-                         "--fail-on-regression)")
-    ap.add_argument("--fail-on-regression", action="store_true",
-                    help="with --check-regression: exit 1 when any "
-                         "tracked metric regressed past the threshold "
-                         "(CI keeps the default warn-only)")
     args = ap.parse_args()
-    if args.check_regression:
-        warns = check_regression()
-        if warns and args.fail_on_regression:
-            raise SystemExit(1)
-        return
-    if args.fail_on_regression:
-        ap.error("--fail-on-regression needs --check-regression")
-    if args.history:
-        whats = (list(PERF_METRICS) if args.what == "all"
-                 else [args.what])
-        for w in whats:
-            print(f"### {w} perf trajectory (last {args.last} "
-                  f"perf-smoke runs)\n")
-            print(history_table(w, last=args.last))
-            print()
-        return
     if args.what in ("all", "dryrun"):
         print("### Dry-run matrix\n")
         print(dryrun_table(args.outdir))

@@ -7,13 +7,10 @@
 engine and emits ``experiments/BENCH_replay.json`` (wall seconds,
 candidate-events/sec, measured speedup vs the scalar oracle) so future
 PRs can track the replay-throughput trajectory.  Every run is stamped
-with its provenance (git sha, jax backend, device kind, timestamp) and
-appended to ``experiments/BENCH_history.jsonl``; with ``POND_TRACE=1``
-the engine counters (jit-cache hits/misses, padding waste, shard
-spans) are merged in and a Chrome trace lands at
+with its provenance (git sha, jax backend, device kind, timestamp);
+with ``POND_TRACE=1`` the engine counters (jit-cache hits/misses,
+padding waste, shard spans) are merged in and a Chrome trace lands at
 ``experiments/trace_perf_smoke.json`` (view on ui.perfetto.dev).
-``benchmarks/report.py --check-regression`` compares the latest
-history entry against the median of the prior runs.
 
 Every run keeps jax's persistent compilation cache
 (``repro.core.compile_cache``): in ``JAX_COMPILATION_CACHE_DIR`` when
@@ -273,12 +270,6 @@ def perf_smoke(cache_dir: str):
     os.makedirs("experiments", exist_ok=True)
     with open("experiments/BENCH_replay.json", "w") as f:
         json.dump(bench, f, indent=1)
-    # append, never overwrite: the perf trajectory across PRs
-    with open("experiments/BENCH_history.jsonl", "a") as f:
-        f.write(json.dumps({"manifest": manifest, "bench": {
-            k: v for k, v in bench.items()
-            if k not in ("manifest", "obs")},
-            "obs": rec.metrics() if rec.enabled else {}}) + "\n")
     if rec.enabled:
         trace_path = rec.to_chrome_trace(
             "experiments/trace_perf_smoke.json", manifest=manifest)
@@ -301,8 +292,7 @@ def perf_smoke(cache_dir: str):
           f"grid {bench['topology_lanes']} lanes "
           f"{bench['topology_speedup_vs_oracle']}x vs oracle "
           f"-> experiments/BENCH_replay.json "
-          f"(history: experiments/BENCH_history.jsonl, "
-          f"sha {manifest['git_sha'][:12]}, {manifest['backend']})")
+          f"(sha {manifest['git_sha'][:12]}, {manifest['backend']})")
     return bench
 
 

@@ -32,10 +32,10 @@ Design:
   https://ui.perfetto.dev to see the span waterfall) and
   :func:`run_manifest` (git sha, jax backend/device kind, versions,
   wall clock) so every benchmark run carries its provenance.
-  ``benchmarks/run.py --perf-smoke`` appends manifest + metrics to
-  ``experiments/BENCH_history.jsonl``;
-  ``benchmarks/report.py --check-regression`` compares the latest
-  entry against the history median.
+* Every span of a live recorder is also a
+  ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+  trace holds the program's spans on its host plane, on the same clock
+  as the device's operations.
 
 Instrumentation must never change results: recorders observe wall
 clock and counts only, and every engine parity test runs unchanged
@@ -106,9 +106,31 @@ _NULL = _NullRecorder()
 
 
 # ------------------------------------------------------------------ spans --
+_TraceAnnotation = None
+
+
+def _annotation(name, args):
+    """A ``jax.profiler.TraceAnnotation`` for one span, or None where
+    jax has not been imported (no profiler session can be running
+    then, and a host without jax never imports it)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **args) if args else \
+        _TraceAnnotation(name)
+
+
 class _Span:
-    """One nested wall-clock span (context manager)."""
-    __slots__ = ("_rec", "name", "args", "_t0")
+    """One nested wall-clock span (context manager).
+
+    It also enters a profiler annotation of the same name and args, so
+    a ``jax.profiler`` trace holds every span on its host plane, on the
+    profiler's clock, beside the device's operations.
+    """
+    __slots__ = ("_rec", "name", "args", "_t0", "_ann")
 
     def __init__(self, rec, name, args):
         self._rec = rec
@@ -116,6 +138,9 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._ann = ann = _annotation(self.name, self.args)
+        if ann is not None:
+            ann.__enter__()
         self._rec._depth += 1
         self._t0 = time.perf_counter_ns()
         return self
@@ -125,6 +150,8 @@ class _Span:
         rec = self._rec
         rec._depth -= 1
         rec._emit(self.name, self._t0, t1, rec._depth, self.args)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -168,7 +195,9 @@ class Recorder:
         double-buffered shard uploads, timed on the upload worker and
         emitted here by the engine thread once the future resolves.
         The recorder itself stays single-threaded: only the engine
-        thread ever calls this.
+        thread ever calls this.  No profiler annotation is written
+        here: the thread that did the work wraps it in its own (the
+        upload worker's ``stream.upload``).
         """
         self._emit(name, t0_ns, t1_ns, self._depth, attrs or None)
 

@@ -302,6 +302,7 @@ class AvailabilityResult:
 class CompiledReplay:
     """One ``(vms, decisions)`` pair compiled for batched replay sweeps."""
 
+    @obs.traced("replay.compile")
     def __init__(self, vms, decisions, cfg, failure_schedule=None):
         self.cfg = cfg
         # references kept for the scalar-oracle availability fallback
@@ -554,6 +555,7 @@ class CompiledReplay:
         np_dt = sweep_core.state_np_dtype(dt_name)
         sweep = sweep_core.get_fail_sweep(dt_name, mitigation,
                                           with_dist=per_failure)
+        rec = obs.get_recorder()
         kind = np.asarray(self._ev_kind)
         fail_pos = np.flatnonzero(kind == FAIL)
         out = {k: np.empty(n0, np.int64) for k in
@@ -567,17 +569,17 @@ class CompiledReplay:
                 width, self.n_servers, self.cores_per_server, s_pad,
                 g_pad, n_slots, np_dt)
             fstate = sweep_core.init_fail_state(n_slots, g_pad)
-            res = sweep(evs, group_of,
-                        *(sweep_core.device_put(a) for a in
-                          (fc0, um0, up0, slots0) + fstate),
-                        sweep_core.device_put(sgb),
-                        sweep_core.device_put(pgb))
-            for key, a in zip(("rejects", "affected", "killed", "remig",
-                               "lost"), res[:5]):
-                out[key][lo:hi] = np.asarray(a)[:hi - lo]
-            if per_failure:
-                dist[:, lo:hi] = \
-                    np.asarray(res[5])[fail_pos, :hi - lo]
+            args = tuple(sweep_core.device_put(a) for a in
+                         (fc0, um0, up0, slots0) + fstate + (sgb, pgb))
+            with rec.span("replay.compute"):
+                res = sweep(evs, group_of, *args)
+                for key, a in zip(("rejects", "affected", "killed",
+                                   "remig", "lost"), res[:5]):
+                    out[key][lo:hi] = np.asarray(a)[:hi - lo]
+                if per_failure:
+                    dist[:, lo:hi] = \
+                        np.asarray(res[5])[fail_pos, :hi - lo]
+            rec.count("sweep.steps", self.n_events)
         return AvailabilityResult(
             reject_rate=out["rejects"] / max(self.n_vms, 1),
             affected=out["affected"], killed=out["killed"],
@@ -637,6 +639,7 @@ class CompiledReplay:
         dt_name = state_dtype or self._pick_state_dtype(sgb_i, pgb_i)
         np_dt = sweep_core.state_np_dtype(dt_name)
         devs = sweep_core.resolve_devices(devices)
+        rec = obs.get_recorder()
         placed = {}                    # per-mesh replicated event tensors
         for lo, hi, width in sweep_core.candidate_chunks(n0):
             mesh = sh_lane = sh_slot = None
@@ -664,14 +667,13 @@ class CompiledReplay:
             fc0, um0, up0, slots0, _ = sweep_core.init_state(
                 width, self.n_servers, self.cores_per_server, s_pad,
                 g_pad, n_slots, np_dt)
-            out = sweep(evs_m, group_m,
-                        sweep_core.device_put(fc0, sh_lane),
-                        sweep_core.device_put(um0, sh_lane),
-                        sweep_core.device_put(up0, sh_lane),
-                        sweep_core.device_put(slots0, sh_slot),
-                        sweep_core.device_put(sgb, sh_lane),
-                        sweep_core.device_put(pgb, sh_lane))
-            rejects[lo:hi] = np.asarray(out)[:hi - lo]
+            args = tuple(sweep_core.device_put(a, sh) for a, sh in zip(
+                (fc0, um0, up0, slots0, sgb, pgb),
+                (sh_lane, sh_lane, sh_lane, sh_slot, sh_lane, sh_lane)))
+            with rec.span("replay.compute"):
+                out = sweep(evs_m, group_m, *args)
+                rejects[lo:hi] = np.asarray(out)[:hi - lo]
+            rec.count("sweep.steps", self.n_events)
         return rejects / max(self.n_vms, 1)
 
     # --------------------------------------------- reference trajectories --
@@ -1181,22 +1183,20 @@ class CompiledReplay:
         pgb_i = np.zeros((n0, p_pad))
         pgb_i[:, :caps_i.shape[1]] = caps_i
         sweep = sweep_core.get_pod_sweep(dt_name)
+        rec = obs.get_recorder()
         for lo, hi, width in sweep_core.candidate_chunks(n0):
             sgb_w, pgb_w, inc_w = sweep_core.pod_lane_arrays(
                 sgb_i, pgb_i, inc, lo, hi, width, np_dt)
             fc0, um0, up0, slots0, pods0, _ = sweep_core.init_pod_state(
                 width, self.n_servers, self.cores_per_server, s_pad,
                 p_pad, n_slots, np_dt)
-            out = sweep(evs,
-                        sweep_core.device_put(inc_w),
-                        sweep_core.device_put(fc0),
-                        sweep_core.device_put(um0),
-                        sweep_core.device_put(up0),
-                        sweep_core.device_put(slots0),
-                        sweep_core.device_put(pods0),
-                        sweep_core.device_put(sgb_w),
-                        sweep_core.device_put(pgb_w))
-            rejects[lo:hi] = np.asarray(out)[:hi - lo]
+            args = tuple(sweep_core.device_put(a) for a in
+                         (inc_w, fc0, um0, up0, slots0, pods0, sgb_w,
+                          pgb_w))
+            with rec.span("replay.compute"):
+                out = sweep(evs, *args)
+                rejects[lo:hi] = np.asarray(out)[:hi - lo]
+            rec.count("sweep.steps", self.n_events)
         return rejects / max(self.n_vms, 1)
 
 
@@ -1613,16 +1613,20 @@ def _upload_pool():
 def _upload_job(build, sharding=None):
     """Worker-side job: pack one shard's host tensors and start the
     device transfer.  Returns ``(device_arrays, t0_ns, t1_ns, nbytes)``
-    so the caller can report the span from the engine thread."""
+    so the caller can report the span from the engine thread.  The work
+    runs inside a ``stream.upload`` profiler annotation, so a profiler
+    trace shows each upload on the worker's own thread line."""
     import jax
-    t0 = time.perf_counter_ns()
-    arrs = build()
-    nbytes = sum(int(a.nbytes) for a in arrs)
-    if sharding is None:
-        out = tuple(jax.device_put(a) for a in arrs)
-    else:
-        out = tuple(jax.device_put(a, sharding) for a in arrs)
-    return out, t0, time.perf_counter_ns(), nbytes
+    with jax.profiler.TraceAnnotation("stream.upload"):
+        t0 = time.perf_counter_ns()
+        arrs = build()
+        nbytes = sum(int(a.nbytes) for a in arrs)
+        if sharding is None:
+            out = tuple(jax.device_put(a) for a in arrs)
+        else:
+            out = tuple(jax.device_put(a, sharding) for a in arrs)
+        t1 = time.perf_counter_ns()
+    return out, t0, t1, nbytes
 
 
 def _count_out_devices(rec, out) -> None:
@@ -2019,9 +2023,10 @@ class CompiledReplayStream:
                                         sweep_core.LANE_PAD)
         self._g_pad = sweep_core.pad_up(self.n_groups,
                                         sweep_core.LANE_PAD)
-        longest = max((len(s["kind"]) for s in self._shards), default=0)
-        self.shard_pad_events = sweep_core.pad_up(longest,
-                                                  sweep_core.EVENT_PAD)
+        #: real (unpadded) events per shard: a shard scan's steps
+        self.shard_events = [len(s["kind"]) for s in self._shards]
+        self.shard_pad_events = sweep_core.pad_up(
+            max(self.shard_events, default=0), sweep_core.EVENT_PAD)
         #: per-sweep device footprint of one shard's event tensor
         #: (6 int32 streams) — THE quantity max_events_per_shard bounds
         self.peak_shard_bytes = 6 * 4 * self.shard_pad_events
@@ -2269,6 +2274,7 @@ class CompiledReplayStream:
                         carry = sweep(evs, group_j, *carry, sgb_j, pgb_j)
                         if rec.enabled:
                             carry[0].block_until_ready()
+                    rec.count("sweep.steps", self.shard_events[si])
                 cand_events += self.shard_pad_events * width
                 if debug:
                     self._debug_check_carry(carry[0], carry[1],
@@ -2429,6 +2435,7 @@ class CompiledReplayStream:
                         carry = sweep(evs, inc_j, *carry, sgb_j, pgb_j)
                         if rec.enabled:
                             carry[0].block_until_ready()
+                    rec.count("sweep.steps", self.shard_events[si])
                 cand_events += self.shard_pad_events * width
                 if reject_cap is not None:
                     if (np.asarray(carry[5])[:kc] > reject_cap).all():
@@ -2564,10 +2571,11 @@ class CompiledReplayBatch:
         """Stack per-trace padded event streams to one (K, E_max) tensor."""
         if self._jax_batch is not None:
             return self._jax_batch
-        cols, group, n_slots, s_pad, g_pad = self._jax_batch_host()
-        self._jax_batch = (tuple(sweep_core.device_put(c) for c in cols),
-                           sweep_core.device_put(group), n_slots, s_pad,
-                           g_pad)
+        with obs.get_recorder().span("batch.upload"):
+            cols, group, n_slots, s_pad, g_pad = self._jax_batch_host()
+            self._jax_batch = (
+                tuple(sweep_core.device_put(c) for c in cols),
+                sweep_core.device_put(group), n_slots, s_pad, g_pad)
         return self._jax_batch
 
     def _jax_batch_placed(self, mesh, k_pad, row_sharded):
@@ -2578,20 +2586,21 @@ class CompiledReplayBatch:
         key = (mesh, k_pad, row_sharded)
         if self._jax_placed is not None and self._jax_placed[0] == key:
             return self._jax_placed[1]
-        cols, group, n_slots, s_pad, g_pad = self._jax_batch_host()
-        fills = (PAD, 0, 0, 0, 0, 0)
-        sh = (sweep_core.named_sharding(mesh, "shard") if row_sharded
-              else sweep_core.named_sharding(mesh))
-        streams = []
-        for col, fill in zip(cols, fills):
-            if k_pad > self.k:
-                col = np.concatenate([col, np.full(
-                    (k_pad - self.k, col.shape[1]), fill, np.int32)])
-            streams.append(sweep_core.device_put(col, sh))
-        data = (tuple(streams),
-                sweep_core.device_put(group,
-                                      sweep_core.named_sharding(mesh)),
-                n_slots, s_pad, g_pad)
+        with obs.get_recorder().span("batch.upload"):
+            cols, group, n_slots, s_pad, g_pad = self._jax_batch_host()
+            fills = (PAD, 0, 0, 0, 0, 0)
+            sh = (sweep_core.named_sharding(mesh, "shard") if row_sharded
+                  else sweep_core.named_sharding(mesh))
+            streams = []
+            for col, fill in zip(cols, fills):
+                if k_pad > self.k:
+                    col = np.concatenate([col, np.full(
+                        (k_pad - self.k, col.shape[1]), fill, np.int32)])
+                streams.append(sweep_core.device_put(col, sh))
+            data = (tuple(streams),
+                    sweep_core.device_put(group,
+                                          sweep_core.named_sharding(mesh)),
+                    n_slots, s_pad, g_pad)
         self._jax_placed = (key, data)
         return data
 
@@ -2664,6 +2673,8 @@ class CompiledReplayBatch:
         else:
             evs, group_of, n_slots, s_pad, g_pad = \
                 self._jax_batch_events()
+        rec = obs.get_recorder()
+        steps = int(self.n_events.max(initial=0))
         for lo, hi, width in sweep_core.candidate_chunks(n0):
             kc = hi - lo
             mesh = sh_state = sh_slot = sh_cap = None
@@ -2698,17 +2709,16 @@ class CompiledReplayBatch:
             fc0, um0, up0, slots0, _ = sweep_core.init_state(
                 width, self.n_servers, self.cores_per_server, s_pad,
                 g_pad, n_slots, np_dt)
-            out = sweep(evs_m, group_m,
-                        sweep_core.device_put(fc0, sh_state),
-                        sweep_core.device_put(um0, sh_state),
-                        sweep_core.device_put(up0, sh_state),
-                        sweep_core.device_put(slots0, sh_slot),
-                        sweep_core.device_put(sgb, sh_cap),
-                        sweep_core.device_put(pgb, sh_cap))
-            rejects[:, lo:hi] = np.asarray(out)[:self.k, :kc]
+            args = tuple(sweep_core.device_put(a, sh) for a, sh in zip(
+                (fc0, um0, up0, slots0, sgb, pgb),
+                (sh_state, sh_state, sh_state, sh_slot, sh_cap, sh_cap)))
+            with rec.span("batch.compute"):
+                out = sweep(evs_m, group_m, *args)
+                rejects[:, lo:hi] = np.asarray(out)[:self.k, :kc]
+            rec.count("sweep.steps", steps)
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
         _STATS.sweeps += 1
-        _STATS.events += int(self.n_events.max(initial=0))
+        _STATS.events += steps
         _STATS.candidate_events += int(self.n_events.sum()) * n0
         _STATS.wall_s += time.perf_counter() - t0
         return rates
@@ -2780,6 +2790,8 @@ class CompiledReplayBatch:
         pgb_i[:, :caps_i.shape[1]] = caps_i
         sweep = sweep_core.get_pod_sweep(dt_name, batched=True,
                                          mesh=mesh)
+        rec = obs.get_recorder()
+        steps = int(self.n_events.max(initial=0))
         for lo, hi, width in sweep_core.candidate_chunks(n0):
             kc = hi - lo
             sgb_w, pgb_w, inc_w = sweep_core.pod_lane_arrays(
@@ -2789,23 +2801,18 @@ class CompiledReplayBatch:
             fc0, um0, up0, slots0, pods0, _ = sweep_core.init_pod_state(
                 width, self.n_servers, self.cores_per_server, s_pad,
                 p_pad, n_slots, np_dt)
-            out = sweep(evs,
-                        sweep_core.device_put(inc_w, sh_rep),
-                        sweep_core.device_put(fc0, sh_rep),
-                        sweep_core.device_put(um0, sh_rep),
-                        sweep_core.device_put(up0, sh_rep),
-                        sweep_core.device_put(slots0, sh_rep),
-                        sweep_core.device_put(pods0, sh_rep),
-                        sweep_core.device_put(
-                            np.broadcast_to(sgb_w, (k_pad,) + sgb_w.shape
-                                            ).copy(), sh_row),
-                        sweep_core.device_put(
-                            np.broadcast_to(pgb_w, (k_pad,) + pgb_w.shape
-                                            ).copy(), sh_row))
-            rejects[:, lo:hi] = np.asarray(out)[:self.k, :kc]
+            args = tuple(sweep_core.device_put(a, sh_rep) for a in
+                         (inc_w, fc0, um0, up0, slots0, pods0)) + tuple(
+                sweep_core.device_put(
+                    np.broadcast_to(a, (k_pad,) + a.shape).copy(), sh_row)
+                for a in (sgb_w, pgb_w))
+            with rec.span("batch.compute"):
+                out = sweep(evs, *args)
+                rejects[:, lo:hi] = np.asarray(out)[:self.k, :kc]
+            rec.count("sweep.steps", steps)
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
         _STATS.sweeps += 1
-        _STATS.events += int(self.n_events.max(initial=0))
+        _STATS.events += steps
         _STATS.candidate_events += int(self.n_events.sum()) * n0
         _STATS.wall_s += time.perf_counter() - t0
         return rates
@@ -2816,18 +2823,19 @@ class CompiledReplayBatch:
         are no-ops (kind PAD, domain -1)."""
         if self._jax_batch_fail is not None:
             return self._jax_batch_fail
-        per = [e._jax_events_fail() for e in self.engines]
-        e_max = max(p[0][0].shape[0] for p in per)
-        n_slots = max(p[2] for p in per)
-        s_pad, g_pad = per[0][3], per[0][4]
-        fills = (PAD, 0, 0, 0, 0, 0, 0, -1)
-        streams = []
-        for j, fill in enumerate(fills):
-            col = np.full((self.k, e_max), fill, np.int32)
-            for i, p in enumerate(per):
-                arr = np.asarray(p[0][j])
-                col[i, :arr.shape[0]] = arr
-            streams.append(sweep_core.device_put(col))
+        with obs.get_recorder().span("batch.upload"):
+            per = [e._jax_events_fail() for e in self.engines]
+            e_max = max(p[0][0].shape[0] for p in per)
+            n_slots = max(p[2] for p in per)
+            s_pad, g_pad = per[0][3], per[0][4]
+            fills = (PAD, 0, 0, 0, 0, 0, 0, -1)
+            streams = []
+            for j, fill in enumerate(fills):
+                col = np.full((self.k, e_max), fill, np.int32)
+                for i, p in enumerate(per):
+                    arr = np.asarray(p[0][j])
+                    col[i, :arr.shape[0]] = arr
+                streams.append(sweep_core.device_put(col))
         self._jax_batch_fail = (tuple(streams), per[0][1], n_slots,
                                 s_pad, g_pad)
         return self._jax_batch_fail
@@ -2884,6 +2892,8 @@ class CompiledReplayBatch:
                                           batched=True, with_dist=False)
         out = {key: np.empty((self.k, n0), np.int64) for key in
                ("rejects", "affected", "killed", "remig", "lost")}
+        rec = obs.get_recorder()
+        steps = int(self.n_events.max(initial=0))
         for lo, hi, width in sweep_core.candidate_chunks(n0):
             kc = hi - lo
             sgb, pgb = sweep_core.lane_capacities(sgb_i, pgb_i, lo, hi,
@@ -2896,16 +2906,16 @@ class CompiledReplayBatch:
                 g_pad, n_slots, np_dt, k=self.k)
             fstate = sweep_core.init_fail_state(n_slots, g_pad,
                                                 k=self.k)
-            res = sweep(evs, group_of,
-                        *(sweep_core.device_put(a) for a in
-                          (fc0, um0, up0, slots0) + fstate),
-                        sweep_core.device_put(sgb),
-                        sweep_core.device_put(pgb))
-            for key, a in zip(("rejects", "affected", "killed", "remig",
-                               "lost"), res[:5]):
-                out[key][:, lo:hi] = np.asarray(a)[:, :kc]
+            args = tuple(sweep_core.device_put(a) for a in
+                         (fc0, um0, up0, slots0) + fstate + (sgb, pgb))
+            with rec.span("batch.compute"):
+                res = sweep(evs, group_of, *args)
+                for key, a in zip(("rejects", "affected", "killed",
+                                   "remig", "lost"), res[:5]):
+                    out[key][:, lo:hi] = np.asarray(a)[:, :kc]
+            rec.count("sweep.steps", steps)
         _STATS.sweeps += 1
-        _STATS.events += int(self.n_events.max(initial=0))
+        _STATS.events += steps
         _STATS.candidate_events += int(self.n_events.sum()) * n0
         _STATS.wall_s += time.perf_counter() - t0
         return AvailabilityResult(
@@ -2978,6 +2988,11 @@ class CompiledReplayStreamBatch:
         self.n_shards = max((s.n_shards for s in streams), default=0)
         self.shard_pad_events = max(
             (s.shard_pad_events for s in streams if s.n_shards), default=0)
+        #: the longest trace's real events in each stacked shard: the
+        #: steps of that shard's scan
+        self.shard_steps = [
+            max(s.shard_events[si] for s in streams if si < s.n_shards)
+            for si in range(self.n_shards)]
         #: device footprint of ONE stacked shard batch (6 int32 streams
         #: x K traces) — THE quantity the composed engine bounds
         self.peak_shard_bytes = self.k * 6 * 4 * self.shard_pad_events
@@ -3211,6 +3226,7 @@ class CompiledReplayStreamBatch:
                         carry = sweep(evs, group_j, *carry, sgb_j, pgb_j)
                         if rec.enabled:
                             carry[0].block_until_ready()
+                    rec.count("sweep.steps", self.shard_steps[si])
                 cand_events += self.k * self.shard_pad_events * width
                 if debug:
                     sweep_core.check_invariants(
@@ -3350,6 +3366,7 @@ class CompiledReplayStreamBatch:
                         carry = sweep(evs, inc_j, *carry, sgb_j, pgb_j)
                         if rec.enabled:
                             carry[0].block_until_ready()
+                    rec.count("sweep.steps", self.shard_steps[si])
                 cand_events += self.k * self.shard_pad_events * width
                 if reject_cap is not None:
                     rej_now = np.asarray(carry[5])[:self.k, :kc]

@@ -163,20 +163,18 @@ def test_observability_doc_exists_and_covers_architecture():
                   # counter catalogue anchors
                   "jit.", "pad.", "device_put", "reject_cap",
                   "checkpoint", "policy.", "ingest.",
-                  # exports + regression tracking
+                  # exports + the profiler bridge
                   "to_chrome_trace", "run_manifest", "perfetto",
-                  "BENCH_history.jsonl", "--what obs", "--history",
-                  "--check-regression", "median", "warn-only",
-                  "test_obs"):
+                  "--what obs", "TraceAnnotation", "jax.profiler",
+                  "batch.compute", "sweep.steps", "test_obs"):
         assert topic.lower() in text.lower(), \
             f"docs/observability.md misses {topic!r}"
 
 
 def test_readme_covers_observability():
     text = _read("README.md")
-    for topic in ("obs.py", "POND_TRACE", "BENCH_history.jsonl",
-                  "docs/observability.md", "--what obs",
-                  "--check-regression", "--history", "perfetto"):
+    for topic in ("obs.py", "POND_TRACE", "jax.profiler",
+                  "docs/observability.md", "--what obs", "perfetto"):
         assert topic.lower() in text.lower(), f"README misses {topic!r}"
 
 

@@ -311,35 +311,176 @@ def test_ingest_counters_identity():
         [(v.vm_id, v.arrival, v.mem_gb) for v in traced]
 
 
-# ------------------------------------------------------- report helpers ---
-def test_history_and_regression_check(tmp_path, capsys):
-    from benchmarks import report
-    hist = tmp_path / "BENCH_history.jsonl"
-    entries = [{"manifest": {"timestamp": f"t{i}", "git_sha": "a" * 40,
-                             "backend": "cpu"},
-                "bench": {"wall_s": 10.0, "events_per_sec": 1e6}}
-               for i in range(3)]
-    # latest run: 2x slower wall, half the throughput -> two warns
-    entries.append({"manifest": {"timestamp": "t3", "git_sha": "b" * 40,
-                                 "backend": "cpu"},
-                    "bench": {"wall_s": 20.0, "events_per_sec": 5e5}})
-    hist.write_text("".join(json.dumps(e) + "\n" for e in entries)
-                    + "{torn line\n")
-    warns = report.check_regression(path=str(hist))
-    assert len(warns) == 2
-    assert any("wall_s" in w for w in warns)
-    assert any("events_per_sec" in w for w in warns)
-    # within-threshold latest -> no warns
-    ok = entries[:3] + [{"manifest": entries[0]["manifest"],
-                         "bench": {"wall_s": 11.0,
-                                   "events_per_sec": 0.95e6}}]
-    hist.write_text("".join(json.dumps(e) + "\n" for e in ok))
-    assert report.check_regression(path=str(hist)) == []
-    # <2 entries: skip, never raise
-    hist.write_text(json.dumps(entries[0]) + "\n")
-    assert report.check_regression(path=str(hist)) == []
-    assert report.check_regression(path=str(tmp_path / "none.jsonl")) \
-        == []
-    table = report.history_table("replay", path=str(hist))
-    assert "wall_s" in table and "t0" in table
-    capsys.readouterr()
+# ------------------------------------------- compute spans, sweep.steps ---
+_SERVER = np.array([200.0, 260.0, 120.0, 300.0])
+_POOL = np.array([64.0, 128.0, 0.0, 256.0])
+
+
+def _n_chunks(n):
+    return len(list(sweep_core.candidate_chunks(n)))
+
+
+def _stream_batch(budget=256):
+    engines = [_small_engine(seed=s, n=400) for s in (2, 3)]
+    streams = [replay_engine.CompiledReplayStream(
+        e._vms, e._decisions_src, e.cfg, max_events_per_shard=budget)
+        for e in engines]
+    return replay_engine.CompiledReplayStreamBatch(streams)
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="needs jax")
+def test_batch_compute_spans_and_steps():
+    """``batch.compute`` once per candidate chunk, ``batch.upload`` only
+    when the stacked events build, ``sweep.steps`` the longest trace's
+    events per chunk, ``replay.compile`` once per engine."""
+    n = sweep_core.JAX_CHUNK + 4                   # two chunks
+    server = np.linspace(150.0, 320.0, n)
+    pool = np.linspace(0.0, 256.0, n)
+    rec = obs.Recorder()
+    with obs.use_recorder(rec):
+        batch = replay_engine.CompiledReplayBatch(
+            [_small_engine(seed=s, n=n_vms)
+             for s, n_vms in ((0, 250), (1, 300))])
+        batch.reject_rates(server, pool)
+    second = obs.Recorder()
+    with obs.use_recorder(second):
+        batch.reject_rates(server, pool)
+    chunks = _n_chunks(n)
+    assert chunks == 2
+    steps = int(batch.n_events.max()) * chunks
+    m, m2 = rec.metrics(), second.metrics()
+    assert m["span.replay.compile.count"] == 2
+    assert m["span.batch.upload.count"] == 1
+    assert "span.batch.upload.count" not in m2
+    for got in (m, m2):
+        assert got["span.batch.compute.count"] == chunks
+        assert got["sweep.steps"] == steps
+        assert got["span.batch.compute.total_s"] \
+            <= got["span.batch.reject_rates.total_s"]
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="needs jax")
+@pytest.mark.parametrize("family", ["plain", "fail", "pod"])
+def test_replay_compute_spans_and_steps(family):
+    """Every kernel family of the single-trace engine: one
+    ``replay.compute`` per chunk and its trace's events as steps."""
+    from repro.core import topology
+    from repro.runtime.fault import FailureSchedule
+    eng = _small_engine(seed=5)
+    if family == "fail":
+        sched = FailureSchedule.generate(3 * 86400.0, eng.cfg.n_groups,
+                                         8 * 3600.0, 1800.0, seed=1)
+        eng = replay_engine.CompiledReplay(eng._vms, eng._decisions_src,
+                                           eng.cfg, failure_schedule=sched)
+    rec = obs.Recorder()
+    with obs.use_recorder(rec):
+        if family == "plain":
+            eng.reject_rates(_SERVER, _POOL)
+        elif family == "fail":
+            eng.availability(_SERVER, _POOL)
+        else:
+            eng.reject_rates_fleet(_SERVER, 128.0,
+                                   topology.partitioned(8, 4))
+    m = rec.metrics()
+    assert m["span.replay.compute.count"] == _n_chunks(len(_SERVER))
+    assert m["sweep.steps"] == eng.n_events * _n_chunks(len(_SERVER))
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="needs jax")
+@pytest.mark.parametrize("case", ["whole", "reject_cap", "skipped"])
+def test_stream_batch_steps_count_dispatched_shards(case):
+    """``sweep.steps`` adds the longest trace's real events of each
+    shard scan that ran: not the shards a ``reject_cap`` exit left
+    undispatched, nor those a divergence window skipped."""
+    batch = _stream_batch()
+    assert batch.n_shards > 2
+    if case == "whole":
+        args = dict(server_gb=_SERVER, pool_gb=_POOL, skip_windows=False)
+    elif case == "reject_cap":          # every lane rejects at once
+        args = dict(server_gb=np.zeros(2), pool_gb=np.zeros(2),
+                    reject_cap=0, skip_windows=False)
+    else:               # capacities that cover the first shard's demand
+        refs = [replay_engine._stream_reference(s) for s in batch.engines]
+        args = dict(server_gb=np.full(2, max(r["max_srv"][0] for r in refs)),
+                    pool_gb=np.full(2, max(r["max_pool"][0] for r in refs)))
+    rec = obs.Recorder()
+    with obs.use_recorder(rec):
+        batch.reject_rates(**args)
+    m = rec.metrics()
+    ran = m.get("span.stream.compute.count", 0)
+    skipped = m.get("stream.shards_skipped", 0)
+    assert skipped + ran <= batch.n_shards
+    assert m.get("sweep.steps", 0) == \
+        sum(batch.shard_steps[skipped:skipped + ran])
+    if case == "whole":
+        assert ran == batch.n_shards
+        assert m["sweep.steps"] == int(batch.n_events.max())
+    elif case == "reject_cap":
+        assert m["stream.reject_cap_exits"] == 1 and ran < batch.n_shards
+    else:
+        assert skipped > 0 and ran > 0
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="needs jax")
+def test_profiler_trace_holds_recorder_spans(tmp_path):
+    """Under ``jax.profiler`` every recorder span is also a host-plane
+    event of the same name and (near) the same duration."""
+    from jax.profiler import ProfileData
+    batch = replay_engine.CompiledReplayBatch(
+        [_small_engine(seed=s) for s in (6, 7)])
+    stream = _stream_batch()
+    rec = obs.Recorder()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.use_recorder(rec):
+            batch.reject_rates(_SERVER, _POOL)
+            stream.reject_rates(_SERVER, _POOL, skip_windows=False)
+    finally:
+        jax.profiler.stop_trace()
+    mine: dict = {}
+    for sp in rec.spans():
+        mine.setdefault(sp["name"], []).append(sp)
+    assert {"batch.upload", "batch.compute", "stream.compute",
+            "stream.upload"} <= set(mine)
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    native: dict = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in mine:
+                        native.setdefault(e.name, []).append(
+                            (e.start_ns, e.duration_ns))
+    for name, spans in mine.items():
+        got = sorted(native.get(name, []))
+        assert len(got) == len(spans), name
+        want = sorted(spans, key=lambda sp: sp["ts_ns"])
+        for (_, dur), sp in zip(got, want):
+            assert abs(dur - sp["dur_ns"]) <= max(
+                0.05 * sp["dur_ns"], 100_000), (name, dur, sp["dur_ns"])
+
+
+@pytest.mark.skipif(not HAS_JAX, reason="needs jax")
+@pytest.mark.parametrize("engine", ["batch", "stream_batch"])
+def test_results_identical_recorder_off_on_profiled(engine, tmp_path):
+    """Rates are bit-identical with the recorder off, on, and on under
+    an active profiler session."""
+    if engine == "batch":
+        eng = replay_engine.CompiledReplayBatch(
+            [_small_engine(seed=s) for s in (8, 9)])
+    else:
+        eng = _stream_batch()
+    off = eng.reject_rates(_SERVER, _POOL)
+    with obs.use_recorder(obs.Recorder()):
+        on = eng.reject_rates(_SERVER, _POOL)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.use_recorder(obs.Recorder()):
+            profiled = eng.reject_rates(_SERVER, _POOL)
+    finally:
+        jax.profiler.stop_trace()
+    assert np.array_equal(off, on) and np.array_equal(off, profiled)
