@@ -15,6 +15,11 @@ around it, so the engine classes stay thin orchestration layers:
   int16) and over whether the packed state is returned as a carry
   (the streaming variant) or consumed whole (the monolithic variant).
 
+* **The same step as one Pallas TPU kernel** (:func:`build_pallas_sweep`):
+  on TPU devices every variant runs a call's whole event loop in one
+  kernel with the state in VMEM, bit for bit the scan's results; the
+  scan stays the CPU path and the kernel's reference.
+
 * **A single keyed jit cache** (:func:`get_sweep`): jitted sweeps are
   cached by ``(state_dtype, with_carry, batched)``.  This replaces the
   old ``_JAX_SWEEPS`` dict + ``_JAX_BATCH_SWEEP`` module globals —
@@ -81,6 +86,7 @@ BUCKETS = (2, 4, 16, 32, JAX_CHUNK)   # padded candidate widths (lazy
 # fixed-cost-dominated per sweep, so padding 1-2 probes to 16 lanes
 # would waste most of the sweep)
 EVENT_PAD = 256       # event-stream pad granularity
+EVENT_BLOCK = 512     # events per SMEM block of the Pallas sweep
 LANE_PAD = 16         # server/group column pad granularity
 SLOT_PAD = 32         # placement-slot pad granularity
 I32_BIG = 1 << 30     # "infinite" capacity in the int32 sweep
@@ -122,6 +128,27 @@ class _FirstCallTimer:
             with obs.get_recorder().span(self.name):
                 return self.fn(*args)
         return self.fn(*args)
+
+
+class _CountedSweep:
+    """A cached plain sweep that counts each dispatch, while a recorder
+    is live, as ``sweep.kernel.pallas`` or ``sweep.kernel.scan`` — how
+    often the Pallas kernel engages.  Attributes (``lower``, ...) are
+    the wrapped function's."""
+    __slots__ = ("fn", "counter")
+
+    def __init__(self, fn, counter):
+        self.fn = fn
+        self.counter = counter
+
+    def __call__(self, *args):
+        rec = obs.get_recorder()
+        if rec.enabled:
+            rec.count(self.counter)
+        return self.fn(*args)
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
 
 
 def jax_importable() -> bool:
@@ -239,6 +266,227 @@ def build_sweep(state_dtype: str = "int32", with_carry: bool = False):
     return sweep_carry if with_carry else sweep
 
 
+def build_pallas_sweep(state_dtype: str = "int32", with_carry: bool = False,
+                       batched: bool = False, *, interpret: bool = False):
+    """The sweep as one Pallas TPU kernel per call: :func:`build_sweep`'s
+    step, bit for bit, with the whole event loop on the chip.
+
+    :func:`build_sweep`'s ``lax.scan`` issues a dozen tiny XLA ops per
+    event.  Here one kernel holds the packed state in VMEM from the
+    first event to the last, in int32 whatever ``state_dtype`` is
+    (:func:`pick_state_dtype` already rules out int16 overflow, so the
+    results are identical):
+
+    * **Layout.** Servers on sublanes, candidate lanes on the 128-wide
+      lane axis, padded to a multiple of 128: free cores and used local
+      GB ``(S, L)``, used pool GB per group ``(G, L)``, the slot array
+      ``(n_slots, L)``, whose row at an event's slot lines up with the
+      state's lanes.  The best fit is a min over sublanes, then the
+      first server that reaches it (``jnp.argmin``'s tie-break).
+    * **Group pool check.** A per-server view of the group pool use
+      ``(S, L)``, updated through the mask ``group_of == g`` beside the
+      per-group rows, so no step gathers.
+    * **Events** stream through SMEM in blocks of :data:`EVENT_BLOCK`
+      on the grid's last, sequential axis; the state loads at a trace's
+      first block and is written out after its last.  PAD, FAIL and
+      RECOVER events touch nothing (nor does the tail that pads the
+      stream to whole blocks).
+    * **Traces** are the grid's first axis: K rows of events and
+      capacities, and either K carried states or one shared initial
+      state.
+
+    The returned function takes and returns exactly what
+    :func:`get_sweep`'s ``(state_dtype, with_carry, batched)`` variant
+    does, in the same dtypes and layouts; the transposes and casts at
+    its boundary run once per call.  ``interpret=True`` runs the kernel
+    in Pallas's interpreter (CPU tests).
+    """
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    dt = jnp.int16 if state_dtype == "int16" else jnp.int32
+    big = state_sentinel(state_dtype)
+    i32 = jnp.int32
+    blk_n = EVENT_BLOCK
+
+    def kernel(ev, sidx_ref, gidx_ref, sgb_ref, pgb_ref, fc_in, um_in,
+               up_in, ups_in, slots_in, rej_in, fc, um, up, slots, rej, ups,
+               *, shared):
+        k0 = 0 if shared else pl.program_id(0)
+
+        @pl.when(pl.program_id(1) == 0)
+        def _load():
+            for src, dst in ((fc_in, fc), (um_in, um), (up_in, up),
+                             (ups_in, ups), (slots_in, slots),
+                             (rej_in, rej)):
+                pltpu.sync_copy(src.at[pl.ds(k0, 1)], dst)
+
+        fc, um, up, ups, slots, rej = (r.at[0] for r in
+                                       (fc, um, up, ups, slots, rej))
+        sgb, pgb = sgb_ref[0], pgb_ref[0]
+        # server and pool-group index of each state element, loaded once
+        sidx, gidx = sidx_ref[...], gidx_ref[...]
+        n_srv = sidx.shape[0]
+
+        def best_fit(ok, f):
+            score = jnp.where(ok, f, big)
+            lo = jnp.min(score, axis=0, keepdims=True)
+            first = jnp.min(jnp.where(score == lo, sidx, n_srv), axis=0,
+                            keepdims=True)
+            return first, lo < big
+
+        def the(oh, a):            # a at each lane's server oh, or 0
+            return jnp.sum(jnp.where(oh, a, 0), axis=0, keepdims=True)
+
+        def add_pool(oh, dp):      # dp GB to the pool group of server oh
+            g = the(oh, gidx)
+            grows = lax.broadcasted_iota(i32, up.shape, 0)
+            up[...] += jnp.where(grows == g, dp, 0)
+            ups[...] += jnp.where(gidx == g, dp, 0)
+
+        def step(i, carry):
+            kind, sl = ev[i], ev[blk_n + i]
+            c, l = ev[2 * blk_n + i], ev[3 * blk_n + i]
+            p, m = ev[4 * blk_n + i], ev[5 * blk_n + i]
+            row = slots.at[pl.ds(sl, 1), :]
+
+            @pl.when(kind == ARRIVE)
+            def _arrive():
+                # u + l <= sgb as u <= sgb - l: one row, not the state
+                f, u = fc[...], um[...]
+                fits = f >= c
+                s1, feas1 = best_fit(
+                    fits & (u <= sgb - l) & (ups[...] <= pgb - p), f)
+                # pool short -> control-plane fallback: all-local
+                s2, feas2 = best_fit(fits & (u <= sgb - m), f)
+                sel = jnp.where(feas1, s1, s2)
+                place = feas1 | feas2
+                oh = (sidx == sel) & place
+                fc[...] = f - jnp.where(oh, c, 0)
+                um[...] = u + jnp.where(oh, jnp.where(feas1, l, m), 0)
+                add_pool(oh, jnp.where(place & feas1, p, 0))
+                row[...] = jnp.where(place, sel * 2 + jnp.where(feas1, 0, 1),
+                                     -1)
+                rej[...] += jnp.where(place, 0, 1)
+
+            @pl.when(kind == DEPART)
+            def _depart():
+                val = row[...]
+                has = val >= 0
+                mg = (val & 1) == 1
+                oh = (sidx == (val >> 1)) & has
+                fc[...] += jnp.where(oh, c, 0)
+                um[...] -= jnp.where(oh, jnp.where(mg, m, l), 0)
+                add_pool(oh, jnp.where(has & ~mg, -p, 0))
+                row[...] = jnp.full(val.shape, -1, i32)
+
+            @pl.when(kind == MIGRATE)            # QoS: pool -> local
+            def _migrate():
+                val = row[...]
+                has = val >= 0
+                oh = (sidx == (val >> 1)) & has
+                u = um[...]
+                act = has & (the(oh, u) <= sgb - p)
+                um[...] = u + jnp.where(oh & act, p, 0)
+                add_pool(oh & act, jnp.where(act, -p, 0))
+                row[...] = jnp.where(act, val | 1, val)
+            return carry
+
+        lax.fori_loop(0, blk_n, step, 0)
+
+    def call(evs, group_of, fc0, um0, up0, slots0, rej0, sgb, pgb):
+        """Events ``(K, E)``, state with a leading axis of K or 1
+        (shared), capacities ``(K, C)``; returns the K final states."""
+        k, n_ev = evs[0].shape
+        ks, c, s = fc0.shape
+        g, n_slots = up0.shape[2], slots0.shape[1]
+        width = pad_up(c, 128)
+        n_blk = -(-n_ev // blk_n)
+        ev = jnp.stack([e.astype(i32) for e in evs], 1)       # (K, 6, E)
+        tail = jnp.broadcast_to(
+            jnp.array([PAD, 0, 0, 0, 0, 0], i32)[None, :, None],
+            (k, 6, n_blk * blk_n - n_ev))
+        ev = jnp.concatenate([ev, tail], 2).reshape(k, 6, n_blk, blk_n)
+        ev = ev.transpose(0, 2, 1, 3).reshape(-1)   # block-major, 1-D
+
+        def pad_lanes(a, fill=0):       # int32, last axis padded to L
+            return jnp.pad(a.astype(i32),
+                           [(0, 0)] * (a.ndim - 1) + [(0, width - c)],
+                           constant_values=fill)
+
+        def lanes_last(a):              # (.., C, X) -> (.., X, L)
+            return pad_lanes(jnp.swapaxes(a, -1, -2))
+
+        state = (lanes_last(fc0), lanes_last(um0), lanes_last(up0),
+                 lanes_last(up0[:, :, group_of]), pad_lanes(slots0, -1),
+                 pad_lanes(rej0)[:, None])
+        caps = [pad_lanes(a)[:, None] for a in (sgb, pgb)]
+        sidx = lax.broadcasted_iota(i32, (s, width), 0)
+        gidx = jnp.broadcast_to(group_of.astype(i32)[:, None], (s, width))
+
+        def block(shape):
+            return pl.BlockSpec((1,) + shape, lambda i, j: (i, 0, 0))
+        shapes = ((s, width), (s, width), (g, width), (n_slots, width),
+                  (1, width))
+        rows = sum(n for n, _ in shapes)
+        # double-buffered outputs, the pool view, capacities, server and
+        # group indices
+        vmem = 4 * width * (2 * rows + 5 * s + 32)
+        whole = pl.BlockSpec((s, width), lambda i, j: (0, 0))
+        out = pl.pallas_call(
+            functools.partial(kernel, shared=ks == 1),
+            grid=(k, n_blk),
+            in_specs=[pl.BlockSpec((6 * blk_n,),
+                                   lambda i, j: (i * n_blk + j,),
+                                   memory_space=pltpu.SMEM),
+                      whole, whole, block((1, width)), block((1, width))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * 6,
+            out_specs=[block(sh) for sh in shapes],
+            out_shape=[jax.ShapeDtypeStruct((k,) + sh, i32)
+                       for sh in shapes],
+            scratch_shapes=[pltpu.VMEM((1, s, width), i32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=max(16 << 20, vmem + (4 << 20))),
+            # a carried state is written back over the buffer it came in
+            input_output_aliases={5: 0, 6: 1, 7: 2, 9: 3, 10: 4}
+            if ks == k else {},
+            interpret=interpret,
+        )(ev, sidx, gidx, *caps, *state)
+        fc, um, up, sl, rej = (a[..., :c] for a in out)
+        return (jnp.swapaxes(fc, 1, 2).astype(dt),
+                jnp.swapaxes(um, 1, 2).astype(dt),
+                jnp.swapaxes(up, 1, 2).astype(dt), sl.astype(dt),
+                rej[:, 0])
+
+    def lead(*a):                      # a leading trace axis of 1
+        return jax.tree.map(lambda x: x[None], a)
+
+    if with_carry:
+        def sweep_carry(evs, group_of, fc0, um0, up0, slots0, rej0, sgb,
+                        pgb):
+            state = (fc0, um0, up0, slots0, rej0, sgb, pgb)
+            if batched:
+                return call(evs, group_of, *state)
+            out = call(*lead(evs), group_of, *lead(*state))
+            return tuple(a[0] for a in out)
+        return sweep_carry
+
+    def sweep(evs, group_of, fc0, um0, up0, slots0, sgb, pgb):
+        # the initial state is shared by every trace: one leading row
+        init = lead(fc0, um0, up0, slots0)
+        if not batched:
+            evs, sgb, pgb = lead(evs, sgb, pgb)
+        rej0 = jnp.zeros((1, sgb.shape[1]), i32)
+        out = call(evs, group_of, *init, rej0, sgb, pgb)[4]
+        return out if batched else out[0]
+    return sweep
+
+
 #: positions of the packed carry in the ``with_carry`` sweep signature
 #: ``(evs, group_of, fc0, um0, up0, slots0, rej0, sgb, pgb)`` — donated
 #: so the shard-to-shard state is reused in place (device-resident)
@@ -275,9 +523,16 @@ def get_sweep(state_dtype: str = "int32", *, with_carry: bool = False,
     runs over the never-sharded server axis), so sharded sweeps are
     bit-exact vs the single-device jit; sharded variants get their own
     cache keys (``(..., device_ids, axis)``).
+
+    On TPU devices (the mesh's, or JAX's default device) every variant
+    is the Pallas kernel of :func:`build_pallas_sweep` instead of the
+    ``lax.scan``, with the same signature, results and donation; its
+    keys end in ``"pallas"`` and its jit-cache stems in ``.pallas1``.
+    Each dispatch counts ``sweep.kernel.pallas`` or ``sweep.kernel.scan``.
     """
     if not jax_importable():
         return None
+    pallas = _on_tpu(mesh)
     if mesh is None:
         key = (state_dtype, with_carry, batched)
         flags = dict(carry=with_carry, batched=batched)
@@ -286,6 +541,9 @@ def get_sweep(state_dtype: str = "int32", *, with_carry: bool = False,
                shard_axis)
         flags = dict(carry=with_carry, batched=batched,
                      mesh=f"{shard_axis}{mesh.size}")
+    if pallas:
+        key += ("pallas",)
+        flags["pallas"] = True
     fn = _SWEEPS.get(key)
     rec = obs.get_recorder()
     if fn is None:
@@ -294,11 +552,14 @@ def get_sweep(state_dtype: str = "int32", *, with_carry: bool = False,
         if rec.enabled:
             rec.count(stem + ".miss")
         with rec.span(stem + ".build"):
-            base = build_sweep(state_dtype, with_carry)
-            if batched and with_carry:
+            if pallas:            # the kernel's grid holds the traces
+                base = build_pallas_sweep(state_dtype, with_carry, batched)
+            else:
+                base = build_sweep(state_dtype, with_carry)
+            if batched and with_carry and not pallas:
                 base = jax.vmap(base, in_axes=((0, 0, 0, 0, 0, 0), None,
                                                0, 0, 0, 0, 0, 0, 0))
-            elif batched:
+            elif batched and not pallas:
                 base = jax.vmap(base,
                                 in_axes=((0, 0, 0, 0, 0, 0), None,
                                          None, None, None, None, 0, 0))
@@ -310,10 +571,20 @@ def get_sweep(state_dtype: str = "int32", *, with_carry: bool = False,
                          if with_carry else ())
         if rec.enabled:
             fn = _FirstCallTimer(fn, stem + ".lower")
+        fn = _CountedSweep(fn, "sweep.kernel." + ("pallas" if pallas
+                                                  else "scan"))
         _SWEEPS[key] = fn
     elif rec.enabled:
         rec.count(_jit_key_name("sweep", state_dtype, **flags) + ".hit")
     return fn
+
+
+def _on_tpu(mesh) -> bool:
+    """Whether the sweep runs on TPU devices: the mesh's, or JAX's
+    default device when there is no mesh."""
+    import jax
+    dev = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
+    return dev.platform == "tpu"
 
 
 def jit_cache_keys() -> list:

@@ -70,7 +70,10 @@ def test_replay_engine_doc_exists_and_covers_architecture():
                   "xla_force_host_platform_device_count",
                   "double-buffer", "stream.overlap_ratio",
                   "skip_windows", "shards_skipped",
-                  "test_device_shard"):
+                  "test_device_shard",
+                  # the Pallas kernel the TPU runs in place of the scan
+                  "build_pallas_sweep", "EVENT_BLOCK", "VMEM",
+                  "test_sweep_pallas"):
         assert topic.lower() in text.lower(), \
             f"docs/replay_engine.md misses {topic!r}"
     # the layer diagram names each layer of the stack
@@ -166,7 +169,8 @@ def test_observability_doc_exists_and_covers_architecture():
                   # exports + the profiler bridge
                   "to_chrome_trace", "run_manifest", "perfetto",
                   "--what obs", "TraceAnnotation", "jax.profiler",
-                  "batch.compute", "sweep.steps", "test_obs"):
+                  "batch.compute", "sweep.steps", "test_obs",
+                  "sweep.kernel.pallas", "sweep.kernel.scan", "pallas1"):
         assert topic.lower() in text.lower(), \
             f"docs/observability.md misses {topic!r}"
 

@@ -11,6 +11,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -92,3 +93,66 @@ def test_paged_attention_compiles(one_chip):
         s((b, per_seq), jnp.int32), s((b,), jnp.int32)).compile()
     assert compiled.out_info.shape == (b, hq, d)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the search cells' unstreamed batch: K=2 3-day traces, ~37k events each
+SEARCH_EVENTS, SEARCH_LANES = 37120, 16
+
+
+@pytest.mark.parametrize("state_dtype", ["int32", "int16"])
+def test_pallas_sweep_compiles(one_chip, state_dtype):
+    """The Pallas sweep, as ``get_sweep`` selects it on a TPU: the
+    streamed carry variant at the main path's shapes and the shared-init
+    batch at the search cells'."""
+    dt = jnp.int16 if state_dtype == "int16" else jnp.int32
+    s = lambda shape, d=dt: _shape(one_chip, shape, d)     # noqa: E731
+    carry = jax.jit(sweep_core.build_pallas_sweep(state_dtype, True, True),
+                    donate_argnums=sweep_core._CARRY_ARGNUMS)
+    compiled = carry.lower(
+        tuple(s((K, EVENTS), jnp.int32) for _ in range(6)),
+        s((SERVERS,), jnp.int32), s((K, LANES, SERVERS)),
+        s((K, LANES, SERVERS)), s((K, LANES, GROUPS)),
+        s((K, SLOTS, LANES)), s((K, LANES), jnp.int32), s((K, LANES)),
+        s((K, LANES))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    fc, um, up, slots, rej = compiled.out_info
+    assert fc.shape == um.shape == (K, LANES, SERVERS) and fc.dtype == dt
+    assert up.shape == (K, LANES, GROUPS) and up.dtype == dt
+    assert slots.shape == (K, SLOTS, LANES) and slots.dtype == dt
+    assert rej.shape == (K, LANES) and rej.dtype == jnp.int32
+    batch = jax.jit(sweep_core.build_pallas_sweep(state_dtype, False, True))
+    compiled = batch.lower(
+        tuple(s((K, SEARCH_EVENTS), jnp.int32) for _ in range(6)),
+        s((SERVERS,), jnp.int32), s((SEARCH_LANES, SERVERS)),
+        s((SEARCH_LANES, SERVERS)), s((SEARCH_LANES, GROUPS)),
+        s((SLOTS, SEARCH_LANES)), s((K, SEARCH_LANES)),
+        s((K, SEARCH_LANES))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (K, SEARCH_LANES)
+
+
+def test_sharded_sweep_selects_pallas_on_tpu_mesh(topo):
+    """A mesh of TPU devices selects the Pallas kernel inside the
+    ``shard_map``: four traces, one per chip of the 2x2 host."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        mesh = Mesh(np.array(topo.devices[:4]), ("shard",))
+        rows, rep = NamedSharding(mesh, P("shard")), NamedSharding(mesh, P())
+        k = 4
+
+        def s(shape, d=jnp.int32, sh=rows):
+            return jax.ShapeDtypeStruct(shape, d, sharding=sh)
+        sweep = sweep_core.get_sweep("int32", with_carry=True, batched=True,
+                                     mesh=mesh, shard_axis="trace")
+        compiled = sweep.lower(
+            tuple(s((k, EVENTS)) for _ in range(6)),
+            s((SERVERS,), sh=rep), s((k, LANES, SERVERS)),
+            s((k, LANES, SERVERS)), s((k, LANES, GROUPS)),
+            s((k, SLOTS, LANES)), s((k, LANES)), s((k, LANES)),
+            s((k, LANES))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert any(key[-1] == "pallas" for key in sweep_core.jit_cache_keys())
