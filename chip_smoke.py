@@ -5,9 +5,10 @@
 
 The main path is the one every paper figure goes through: seeded VM
 traces get policy decisions, their events compile into streamed shards,
-and the ``sweep_core`` ``lax.scan`` kernels price the lockstep
-provisioning searches of ``cluster_sim.savings_analysis_batched`` for
-the local, static and pond policies (the searches
+and the ``sweep_core`` sweep kernels (on a TPU one Pallas kernel per
+call) price the lockstep provisioning searches of
+``cluster_sim.savings_analysis_batched`` for the local, static and pond
+policies (the searches
 ``examples/cluster_savings.py`` runs, with its trained LI/UM control
 planes).  The cluster is 256 servers x 64 cores in pool groups of 16
 sockets; K=2 traces cover 30 days at 75% core utilization, streamed in
@@ -20,7 +21,8 @@ sweep may fall back to the host numpy path (``replay.backend_numpy``).
 priced with ``devices=4`` on the trace axis (``CompiledReplayStreamBatch``)
 and on the candidate-lane axis (one ``CompiledReplayStream``), each equal
 bit for bit to the same sweep on one device, with outputs spread over
-four devices.
+four devices and every dispatch counted as the Pallas kernel
+(``sweep.kernel.pallas``).
 
 The script exits non-zero, before any work, when JAX's first device is
 not a TPU, and whenever a check fails.  Its last line of standard output
@@ -218,9 +220,13 @@ def sharded(n_servers: int = 256, days: float = 30.0, k: int = 4,
             n_devices: int = 4, n_vms: int | None = None,
             max_events_per_shard: int = 65536) -> dict:
     """Four-chip phase: trace- and lane-sharded sweeps against one device."""
+    import jax
     import numpy as np
     from repro.core import cluster_sim, obs, replay_engine
 
+    # every sweep dispatch runs the kernel the platform selects
+    kernel, other = ("pallas", "scan") if jax.devices()[0].platform \
+        == "tpu" else ("scan", "pallas")
     cfg = _cluster(n_servers)
     _, _, vms_list = _sample(cfg, days, k, n_vms)
     streams = [replay_engine.CompiledReplayStream(
@@ -249,18 +255,28 @@ def sharded(n_servers: int = 256, days: float = 30.0, k: int = 4,
         one, many = runs[None], runs[n_devices]
         spread = {key: v for key, v in many[2].items()
                   if key.startswith("sweep.out_devices.")}
+        counts = {name: [r[2].get(key, 0) for r in (one, many)]
+                  for name, key in (
+                      ("dispatches", "span.stream.compute.count"),
+                      (kernel, f"sweep.kernel.{kernel}"),
+                      (other, f"sweep.kernel.{other}"))}
         report[plan] = {
             "bit_exact": bool(np.array_equal(one[0], many[0])),
-            "out_devices": spread,
+            "out_devices": spread, "kernel_counts": counts,
             "backend_numpy": one[2].get("replay.backend_numpy", 0)
             + many[2].get("replay.backend_numpy", 0),
             "wall_s_one": one[1], "wall_s_sharded": many[1]}
         _log(f"{plan} plan: bit-exact {report[plan]['bit_exact']}, "
              f"outputs {spread}, wall {one[1]:.2f}s on 1 device vs "
              f"{many[1]:.2f}s on {n_devices} (first calls, compiles "
-             f"included)")
+             f"included); dispatches, by kernel, on 1 and on "
+             f"{n_devices} devices {counts}")
     report["ok"] = all(
         report[plan]["bit_exact"] and report[plan]["backend_numpy"] == 0
+        and report[plan]["kernel_counts"][kernel]
+        == report[plan]["kernel_counts"]["dispatches"]
+        and min(report[plan]["kernel_counts"]["dispatches"]) > 0
+        and not any(report[plan]["kernel_counts"][other])
         and set(report[plan]["out_devices"])
         == {f"sweep.out_devices.{n_devices}"}
         for plan, _ in plans)

@@ -109,42 +109,33 @@ def _jit_key_name(family: str, state_dtype: str, **flags) -> str:
 
 
 class _FirstCallTimer:
-    """Times the FIRST invocation of a freshly jitted sweep — XLA
-    tracing + lowering + compile all happen there — as a
-    ``jit.<family>.<key>.lower`` span, then delegates with one
-    attribute hop.
-    Installed only while a recorder is live (cache misses with tracing
-    disabled store the bare jitted fn, zero steady-state overhead)."""
-    __slots__ = ("fn", "name", "_first")
+    """A cached jitted sweep: times its FIRST call — XLA tracing +
+    lowering + compile all happen there — as a
+    ``jit.<family>.<key>.lower`` span, and, given a ``counter``, counts
+    every dispatch under it while a recorder is live.  One attribute
+    hop; other attributes (``lower``, ...) are the jitted function's.
+    The failure and pod sweeps get it only while a recorder is live
+    (cache misses with tracing disabled store the bare jitted fn);
+    the plain sweep always, as ``sweep.kernel.pallas`` /
+    ``sweep.kernel.scan`` counts its dispatches whenever tracing is
+    on, a sweep built while it was off included."""
+    __slots__ = ("fn", "name", "counter", "_first")
 
-    def __init__(self, fn, name):
+    def __init__(self, fn, name, counter=None):
         self.fn = fn
         self.name = name
+        self.counter = counter
         self._first = True
 
     def __call__(self, *args):
+        if self.counter is not None:
+            rec = obs.get_recorder()
+            if rec.enabled:
+                rec.count(self.counter)
         if self._first:
             self._first = False
             with obs.get_recorder().span(self.name):
                 return self.fn(*args)
-        return self.fn(*args)
-
-
-class _CountedSweep:
-    """A cached plain sweep that counts each dispatch, while a recorder
-    is live, as ``sweep.kernel.pallas`` or ``sweep.kernel.scan`` — how
-    often the Pallas kernel engages.  Attributes (``lower``, ...) are
-    the wrapped function's."""
-    __slots__ = ("fn", "counter")
-
-    def __init__(self, fn, counter):
-        self.fn = fn
-        self.counter = counter
-
-    def __call__(self, *args):
-        rec = obs.get_recorder()
-        if rec.enabled:
-            rec.count(self.counter)
         return self.fn(*args)
 
     def __getattr__(self, name):
@@ -569,10 +560,8 @@ def get_sweep(state_dtype: str = "int32", *, with_carry: bool = False,
                     shard_axis))
             fn = jax.jit(base, donate_argnums=_CARRY_ARGNUMS
                          if with_carry else ())
-        if rec.enabled:
-            fn = _FirstCallTimer(fn, stem + ".lower")
-        fn = _CountedSweep(fn, "sweep.kernel." + ("pallas" if pallas
-                                                  else "scan"))
+        fn = _FirstCallTimer(fn, stem + ".lower", "sweep.kernel."
+                             + ("pallas" if pallas else "scan"))
         _SWEEPS[key] = fn
     elif rec.enabled:
         rec.count(_jit_key_name("sweep", state_dtype, **flags) + ".hit")

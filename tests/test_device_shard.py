@@ -121,6 +121,87 @@ def test_sharded_bit_exact_on_forced_host_devices():
         r.stdout[-2000:] + r.stderr[-2000:]
 
 
+# The same sharded engines with the sweep selected as on a TPU (the
+# platform stubbed as ``test_sweep_pallas._stub_tpu`` does, the Pallas
+# kernel run by its interpreter): each mesh variant wraps the kernel in
+# ``shard_map`` and must match the single-device scan bit for bit.
+_SUBPROC_PALLAS = r"""
+import functools, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+from repro.core import cluster_sim, obs, replay_engine, sweep_core, traces
+
+cfg = cluster_sim.ClusterConfig(n_servers=8, cores_per_server=16,
+                                pool_sockets=8, gb_per_core=4.75)
+sgb = np.linspace(120., 400., 5)
+pgb = np.linspace(0., 900., 5)
+
+
+def mk(seed):
+    vms = traces.Population(seed=0).sample_vms(250, 2 * 86400,
+                                               seed=seed,
+                                               start_id=10 ** 6)
+    dec, _ = cluster_sim.policy_decisions(vms, "static",
+                                          static_pool_frac=0.3)
+    return vms, dec
+
+
+import jax
+assert len(jax.devices()) == 4, jax.devices()
+streams = [replay_engine.CompiledReplayStream(
+    *mk(20 + i), cfg, max_events_per_shard=256) for i in range(3)]
+engines = [replay_engine.CompiledReplay(*mk(40 + i), cfg)
+           for i in range(3)]
+# (engine, devices, state dtype)
+if sys.argv[1] == "trace":    # K rows split over the mesh, K % n uneven
+    calls = [(replay_engine.CompiledReplayStreamBatch(streams), 2, "int32"),
+             (replay_engine.CompiledReplayStreamBatch(streams), 2, "int16"),
+             (replay_engine.CompiledReplayBatch(engines), 2, "int32"),
+             (replay_engine.CompiledReplayBatch(engines), 3, "int16")]
+else:                         # the candidate lanes split over the mesh
+    calls = [(streams[0], 4, "int32"), (engines[0], 4, "int16"),
+             (replay_engine.CompiledReplayBatch(engines[:2]), 4, "int32")]
+
+
+def rates(engine, **kw):
+    if isinstance(engine, (replay_engine.CompiledReplayStream,
+                           replay_engine.CompiledReplayStreamBatch)):
+        kw["skip_windows"] = False
+    return engine.reject_rates(sgb, pgb, **kw)
+
+
+want = [rates(e, state_dtype=dt) for e, _, dt in calls]
+sweep_core._on_tpu = lambda mesh: True
+sweep_core.build_pallas_sweep = functools.partial(
+    sweep_core.build_pallas_sweep, interpret=True)
+sweep_core._SWEEPS.clear()
+rec = obs.Recorder()
+with obs.use_recorder(rec):
+    got = [rates(e, devices=n, state_dtype=dt) for e, n, dt in calls]
+for w, g in zip(want, got):
+    assert w.tolist() == g.tolist(), (w, g)
+m = rec.metrics()
+dispatches = sum(v for k, v in m.items() if k.startswith("span.")
+                 and k.endswith(".compute.count"))
+assert m["sweep.kernel.pallas"] == dispatches >= len(calls), m
+assert "sweep.kernel.scan" not in m, m
+keys = sweep_core.jit_cache_keys()
+assert keys and all(k[-2:] == (sys.argv[1], "pallas") for k in keys), keys
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("shard_axis", ["trace", "lane"])
+def test_sharded_pallas_kernel_matches_scan(shard_axis):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env.pop("XLA_FLAGS", None)          # the script sets its own
+    r = subprocess.run([sys.executable, "-c", _SUBPROC_PALLAS, shard_axis],
+                       env=env, capture_output=True, text=True, timeout=900)
+    assert r.returncode == 0 and "OK" in r.stdout, \
+        r.stdout[-2000:] + r.stderr[-2000:]
+
+
 # -------------------------------------------- in-process (device pool) --
 def test_resolve_devices_semantics():
     import jax

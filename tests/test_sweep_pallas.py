@@ -221,3 +221,37 @@ def test_pallas_carry_variant_donates_its_state(on_tpu, state_dtype):
     for a, b in zip(out, init):
         assert a.shape == b.shape and a.dtype == b.dtype
     assert out[4].dtype == jnp.int32
+
+
+def test_cached_sweep_counts_times_and_lowers(monkeypatch):
+    """One wrapper on a cached plain sweep: it counts every dispatch
+    while a recorder is live (a sweep built untraced included), times
+    the first call as the jit stem's ``.lower`` span, and still hands
+    out the jitted function's ``lower``."""
+    monkeypatch.setattr(sweep_core, "_SWEEPS", {})
+    (evs, n_slots), width = _trace(1, 120), 4
+    n_slots = sweep_core.pad_up(n_slots, sweep_core.SLOT_PAD)
+    init = sweep_core.init_state(width, N_SERVERS, CORES, S_PAD, G_PAD,
+                                 n_slots, np.int32)
+    sgb, pgb = _lanes(width, np.int32, 0)
+    group_of = (np.arange(S_PAD) % N_SERVERS // SPG).astype(np.int32)
+    args = (tuple(evs), group_of, *init[:4], sgb, pgb)
+    traced, untraced = obs.Recorder(), obs.Recorder()
+    with obs.use_recorder(traced):
+        sweep = sweep_core.get_sweep("int32")
+        assert "sweep" in sweep.lower(*args).as_text()
+        first = np.asarray(sweep(*args))
+        assert sweep_core.get_sweep("int32") is sweep
+        assert np.array_equal(np.asarray(sweep(*args)), first)
+    m = traced.metrics()
+    assert m["sweep.kernel.scan"] == 2
+    assert m["span.jit.sweep.int32.carry0.batched0.lower.count"] == 1
+    assert m["jit.sweep.int32.carry0.batched0.miss"] == 1
+    sweep_core._SWEEPS.clear()
+    plain = sweep_core.get_sweep("int32")        # built untraced
+    plain(*args)
+    with obs.use_recorder(untraced):
+        assert np.array_equal(np.asarray(plain(*args)), first)
+    m = untraced.metrics()
+    assert m["sweep.kernel.scan"] == 1
+    assert not any(k.endswith(".lower.count") for k in m)

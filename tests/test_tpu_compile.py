@@ -156,3 +156,47 @@ def test_sharded_sweep_selects_pallas_on_tpu_mesh(topo):
         jax.config.update("jax_enable_compilation_cache", was)
     assert "tpu_custom_call" in compiled.as_text()
     assert any(key[-1] == "pallas" for key in sweep_core.jit_cache_keys())
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_lane_sharded_sweep_selects_pallas_on_tpu_mesh(topo, batched):
+    """The candidate-lane plan on the 2x2 host: events replicated, the
+    lanes split four ways, the Pallas kernel inside the ``shard_map`` —
+    one stream's carried shard sweep (``chip_smoke.py --chips 4``) and
+    the shared-init batch a search of K=2 traces runs on four chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        mesh = Mesh(np.array(topo.devices[:4]), ("shard",))
+        rep = NamedSharding(mesh, P())
+        lead = (K,) if batched else ()
+        lanes = NamedSharding(mesh, P(*(None,) * len(lead), "shard"))
+        cols = NamedSharding(mesh, P(*(None,) * (len(lead) + 1), "shard"))
+
+        def s(shape, sh, d=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, d, sharding=sh)
+        sweep = sweep_core.get_sweep("int32", with_carry=not batched,
+                                     batched=batched, mesh=mesh,
+                                     shard_axis="lane")
+        evs = tuple(s(lead + (EVENTS,), rep) for _ in range(6))
+        if batched:        # the initial state has no trace axis
+            init = (s((LANES, SERVERS), NamedSharding(mesh, P("shard"))),
+                    s((LANES, SERVERS), NamedSharding(mesh, P("shard"))),
+                    s((LANES, GROUPS), NamedSharding(mesh, P("shard"))),
+                    s((SLOTS, LANES), NamedSharding(mesh, P(None, "shard"))))
+        else:
+            init = (s((LANES, SERVERS), lanes), s((LANES, SERVERS), lanes),
+                    s((LANES, GROUPS), lanes), s((SLOTS, LANES), cols),
+                    s((LANES,), lanes))
+        compiled = sweep.lower(
+            evs, s((SERVERS,), rep), *init, s(lead + (LANES,), lanes),
+            s(lead + (LANES,), lanes)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert "tpu_custom_call" in compiled.as_text()
+    out = compiled.out_info
+    rej = out if batched else out[4]
+    assert rej.shape == lead + (LANES,) and rej.dtype == jnp.int32
+    assert any(key[-2:] == ("lane", "pallas")
+               for key in sweep_core.jit_cache_keys())
