@@ -131,6 +131,7 @@ orchestration layers over that shared core (see
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import os
 import time
@@ -674,6 +675,10 @@ class CompiledReplay:
                 out = sweep(evs_m, group_m, *args)
                 rejects[lo:hi] = np.asarray(out)[:hi - lo]
             rec.count("sweep.steps", self.n_events)
+            if rec.enabled:
+                _count_scanned(rec, hi - lo, self._ev_kind.count(ARRIVE),
+                               self.n_events, self.n_servers,
+                               self.n_groups, 2)
         return rejects / max(self.n_vms, 1)
 
     # --------------------------------------------- reference trajectories --
@@ -1637,6 +1642,35 @@ def _count_out_devices(rec, out) -> None:
         rec.count(f"sweep.out_devices.{len(out.devices())}")
 
 
+def _count_scanned(rec, lanes: int, arrivals: int, events: int,
+                   n_servers: int, n_groups: int, carry_rows: int) -> None:
+    """The work one plain sweep dispatch really scanned, whole or a
+    shard of a skipping or capped stream: ``sweep.lane_arrivals``
+    (arrivals summed over the call's traces, times its real candidate
+    lanes), ``sweep.fit_cells`` (that times the servers each arrival's
+    best fit compares), ``sweep.events_scanned`` (real events summed
+    over the traces) and ``sweep.carry_cells`` (the per-server and
+    per-group state entries the dispatch takes in and gives back:
+    ``carry_rows`` lane sets of ``2 * n_servers + n_groups``).  Called
+    only while a recorder is live, as its arguments may cost a count."""
+    rec.count("sweep.lane_arrivals", arrivals * lanes)
+    rec.count("sweep.fit_cells", arrivals * lanes * n_servers)
+    rec.count("sweep.events_scanned", events)
+    rec.count("sweep.carry_cells",
+              carry_rows * lanes * (2 * n_servers + n_groups))
+
+
+def _count_unscanned(rec, steps, first: int, end: int) -> None:
+    """``stream.steps_unscanned``: the event steps (``steps`` per shard,
+    counted as ``sweep.steps`` counts them) of one streamed candidate
+    chunk that no dispatch scanned, because window skipping started it
+    at shard ``first`` or a ``reject_cap`` exit stopped it before
+    shard ``end``."""
+    if rec.enabled:
+        rec.count("stream.steps_unscanned",
+                  int(sum(steps[:first])) + int(sum(steps[end:])))
+
+
 # --------------------------------------------- divergence windows --
 def _stream_reference(stream):
     """Infinite-capacity reference replay over a stream's shards.
@@ -2162,6 +2196,13 @@ class CompiledReplayStream:
             cores_per_server=self.cores_per_server, shard=si,
             up_slack=self._mig_pool_sum)
 
+    @functools.cached_property
+    def shard_arrivals(self) -> list:
+        """Arrival events per shard: the best fits its scan makes.
+        Counted on first use, which only a live recorder makes."""
+        return [int(np.count_nonzero(s["kind"] == ARRIVE))
+                for s in self._shards]
+
     def _shard_host(self, si: int):
         """Builder for one shard's six int32 event columns — runs on
         the upload worker so host packing overlaps device compute."""
@@ -2258,6 +2299,7 @@ class CompiledReplayStream:
             if shard_from < self.n_shards:
                 fut = pool.submit(_upload_job, self._shard_host(shard_from),
                                   sh_rep)
+            end = self.n_shards
             for si in range(shard_from, self.n_shards):
                 with rec.span("stream.shard", shard=si, chunk=ci):
                     with rec.span("stream.upload_wait", shard=si):
@@ -2275,6 +2317,10 @@ class CompiledReplayStream:
                         if rec.enabled:
                             carry[0].block_until_ready()
                     rec.count("sweep.steps", self.shard_events[si])
+                    if rec.enabled:
+                        _count_scanned(rec, k, self.shard_arrivals[si],
+                                       self.shard_events[si],
+                                       self.n_servers, self.n_groups, 2)
                 cand_events += self.shard_pad_events * width
                 if debug:
                     self._debug_check_carry(carry[0], carry[1],
@@ -2290,7 +2336,9 @@ class CompiledReplayStream:
                     rej_now = np.asarray(carry[4])[:k]
                     if (rej_now > reject_cap).all():
                         rec.count("stream.reject_cap_exits")
+                        end = si + 1
                         break                   # every candidate decided
+            _count_unscanned(rec, self.shard_events, shard_from, end)
             rejects[lo:hi] = np.asarray(carry[4])[:k]
             _count_out_devices(rec, carry[4])
         if io is not None:
@@ -2420,6 +2468,7 @@ class CompiledReplayStream:
             pgb_j = sweep_core.device_put(pgb_w)
             pool = _upload_pool()
             fut = pool.submit(_upload_job, self._shard_host(0))
+            end = self.n_shards
             for si in range(self.n_shards):
                 with rec.span("stream.fleet.shard", shard=si):
                     with rec.span("stream.upload_wait", shard=si):
@@ -2440,7 +2489,9 @@ class CompiledReplayStream:
                 if reject_cap is not None:
                     if (np.asarray(carry[5])[:kc] > reject_cap).all():
                         rec.count("stream.reject_cap_exits")
+                        end = si + 1
                         break
+            _count_unscanned(rec, self.shard_events, 0, end)
             rejects[lo:hi] = np.asarray(carry[5])[:kc]
         return rejects, cand_events
 
@@ -2716,6 +2767,13 @@ class CompiledReplayBatch:
                 out = sweep(evs_m, group_m, *args)
                 rejects[:, lo:hi] = np.asarray(out)[:self.k, :kc]
             rec.count("sweep.steps", steps)
+            if rec.enabled:
+                # the initial state is shared: one lane set in, K out
+                _count_scanned(rec, kc,
+                               sum(e._ev_kind.count(ARRIVE)
+                                   for e in self.engines),
+                               int(self.n_events.sum()), self.n_servers,
+                               self.engines[0].n_groups, 1 + self.k)
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
         _STATS.sweeps += 1
         _STATS.events += steps
@@ -3207,6 +3265,7 @@ class CompiledReplayStreamBatch:
                 fut = pool.submit(
                     _upload_job, self._stacked_shard_host(shard_from,
                                                           k_pad), sh_row)
+            end = self.n_shards
             for si in range(shard_from, self.n_shards):
                 with rec.span("stream_batch.shard", shard=si, chunk=ci):
                     with rec.span("stream.upload_wait", shard=si):
@@ -3227,6 +3286,13 @@ class CompiledReplayStreamBatch:
                         if rec.enabled:
                             carry[0].block_until_ready()
                     rec.count("sweep.steps", self.shard_steps[si])
+                    if rec.enabled:
+                        live = [s for s in self.engines if si < s.n_shards]
+                        _count_scanned(
+                            rec, kc,
+                            sum(s.shard_arrivals[si] for s in live),
+                            sum(s.shard_events[si] for s in live),
+                            self.n_servers, self.n_groups, 2 * self.k)
                 cand_events += self.k * self.shard_pad_events * width
                 if debug:
                     sweep_core.check_invariants(
@@ -3248,7 +3314,9 @@ class CompiledReplayStreamBatch:
                     rej_now = np.asarray(carry[4])[:self.k, :kc]
                     if (rej_now > reject_cap).all():
                         rec.count("stream.reject_cap_exits")
+                        end = si + 1
                         break               # every lane decided
+            _count_unscanned(rec, self.shard_steps, shard_from, end)
             rejects[:, lo:hi] = np.asarray(carry[4])[:self.k, :kc]
             _count_out_devices(rec, carry[4])
         if io is not None:
@@ -3349,6 +3417,7 @@ class CompiledReplayStreamBatch:
                 sh_row)
             fut = pool.submit(_upload_job,
                               self._stacked_shard_host(0, k_pad), sh_row)
+            end = self.n_shards
             for si in range(self.n_shards):
                 with rec.span("stream_batch.fleet.shard", shard=si):
                     with rec.span("stream.upload_wait", shard=si):
@@ -3372,7 +3441,9 @@ class CompiledReplayStreamBatch:
                     rej_now = np.asarray(carry[5])[:self.k, :kc]
                     if (rej_now > reject_cap).all():
                         rec.count("stream.reject_cap_exits")
+                        end = si + 1
                         break
+            _count_unscanned(rec, self.shard_steps, 0, end)
             rejects[:, lo:hi] = np.asarray(carry[5])[:self.k, :kc]
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
         _STATS.sweeps += 1

@@ -522,6 +522,7 @@ def _resilient_raw_chunks(path: str, chunk_vms: int, io_retries: int,
             _sleep(io_backoff_s * 2 ** (attempt - 1))
 
 
+@obs.traced("ingest.load")
 def load_trace_file(path: str, max_vms: int | None = None,
                     start_id: int = 0, seed: int = 0,
                     population: "Population | None" = None) -> list[VM]:
@@ -543,6 +544,9 @@ def load_trace_file(path: str, max_vms: int | None = None,
     columns, non-numeric/non-finite cells, non-positive lifetimes,
     cores < 1, or mem_gb <= 0 — with the offending row in the message.
 
+    While a recorder is live (``core/obs.py``) each call is an
+    ``ingest.load`` span and adds its rows to ``ingest.rows``.
+
     Usage::
 
         vms = traces.load_trace_file("azure_2019.csv.gz", max_vms=50_000)
@@ -553,6 +557,9 @@ def load_trace_file(path: str, max_vms: int | None = None,
     n = len(cols["arrival"])
     if n == 0:
         raise TraceSchemaError(f"{path}: trace has no rows")
+    rec = obs.get_recorder()
+    if rec.enabled:
+        rec.count("ingest.rows", n)
 
     arrival, lifetime, cores, mem = _schema_arrays(cols, path)
 
