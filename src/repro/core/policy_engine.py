@@ -10,13 +10,13 @@ vectorizes the entire decide→place→monitor→mitigate pipeline:
 
 * **struct-of-arrays traces** — ``traces.vm_table`` compiles a VM list
   into column arrays once; every stage below reads whole columns.
-* **history percentiles as sorted segment ops** — the per-customer
+* **history percentiles from one rank structure** — the per-customer
   untouched-memory history grows by one observation per VM
   (``record_untouched``), and the UM features need ``np.percentile`` of
-  every PREFIX of that stream.  ``_prefix_percentiles`` sorts each
-  customer's seed+append values once and answers all prefixes' order
-  statistics with cumulative-membership counts (blocked to bound
-  memory), then applies numpy's exact linear-interpolation lerp —
+  every PREFIX of that stream.  ``_prefix_percentiles`` ranks every
+  customer's seed+append values with one sort, answers all prefixes'
+  order statistics from one wavelet matrix over those ranks in
+  O(M log M), then applies numpy's exact linear-interpolation lerp —
   including its ``gamma >= 0.5`` branch — so every feature is
   bit-identical to the scalar walk's ``np.percentile`` call.
 * **batched model inference** — one ``predict_proba_batch`` call scores
@@ -77,8 +77,6 @@ _QS = (80.0, 90.0, 95.0, 99.0)
 _PRIOR = 0.5          # no-history feature prior
 _MIN_HIST_FEAT = 3    # metadata_features' hardcoded history floor
 _MONITOR_DELAY = 60.0  # scalar loop samples QoS at arrival + 60s
-#: column budget (elements) for one prefix-membership block
-_PREFIX_BLOCK_ELEMS = 4_000_000
 
 
 # ------------------------------------------------------------- decisions ---
@@ -168,6 +166,43 @@ def _np_lerp(a: np.ndarray, b: np.ndarray, t: np.ndarray) -> np.ndarray:
     return out
 
 
+def _rank_levels(ranks: np.ndarray) -> list[np.ndarray]:
+    """A wavelet matrix over ``ranks``, (M,) non-negative int32 values.
+
+    One level per bit of the largest value, the most significant first.
+    A level keeps the running count of 0-bits over its entries, an
+    (M + 1,) int32 array, then stably moves its 0-bit entries before its
+    1-bit ones to give the next level's order.
+    """
+    levels = []
+    cur = ranks
+    for b in range(int(ranks.max()).bit_length() - 1, -1, -1):
+        zero = (cur >> b) & 1 == 0
+        zeros = np.zeros(len(cur) + 1, np.int32)
+        np.cumsum(zero, out=zeros[1:])
+        levels.append(zeros)
+        cur = np.concatenate([cur[zero], cur[~zero]])
+    return levels
+
+
+def _kth_smallest(levels: list[np.ndarray], lo: np.ndarray,
+                  hi: np.ndarray, k: np.ndarray) -> np.ndarray:
+    """The ``k``-th smallest (from 0) of ``ranks[lo:hi]`` for every
+    query at once, on :func:`_rank_levels`' matrix: one gather-and-select
+    step per level narrows each query's range to the entries that share
+    the answer's bits so far."""
+    out = np.zeros(np.shape(k), np.int32)
+    for b, zeros in zip(range(len(levels) - 1, -1, -1), levels):
+        zlo, zhi = zeros[lo], zeros[hi]
+        n0 = zhi - zlo                  # 0-bit entries in the range
+        one = k >= n0
+        lo = np.where(one, zeros[-1] + lo - zlo, zlo)
+        hi = np.where(one, zeros[-1] + hi - zhi, zhi)
+        k = np.where(one, k - n0, k)
+        out |= one.astype(np.int32) << b
+    return out
+
+
 def _prefix_percentiles(customers: np.ndarray, untouched: np.ndarray,
                         history: dict | None,
                         qs=_QS) -> tuple[np.ndarray, np.ndarray]:
@@ -182,12 +217,13 @@ def _prefix_percentiles(customers: np.ndarray, untouched: np.ndarray,
     * ``percs``   — (N, len(qs)) float64, ``np.percentile(h, qs)``
       bit-for-bit where ``n_hist >= 3``, the 0.5 prior row elsewhere.
 
-    Instead of re-sorting each prefix (the per-VM history walk), each
-    customer's seed+append values are sorted ONCE; a cumulative count
-    of prefix membership over the sorted order answers every prefix's
-    order statistics at the ranks the linear-interpolation formula
-    needs, in column blocks that bound the membership matrix to
-    ``_PREFIX_BLOCK_ELEMS`` elements.
+    Instead of re-sorting each prefix (the per-VM history walk), every
+    customer's seed+append sequence is laid end to end in one array,
+    whose values are ranked by one sort.  A wavelet matrix over the
+    ranks (:func:`_rank_levels`) then answers the order statistics that
+    numpy's linear interpolation needs, for every prefix at once
+    (:func:`_kth_smallest`): O(M log M) for M values in all, whatever
+    the customers' sizes.
     """
     cust = np.asarray(customers, np.int64)
     ut = np.asarray(untouched, float)
@@ -199,46 +235,43 @@ def _prefix_percentiles(customers: np.ndarray, untouched: np.ndarray,
         return n_hist, percs
     hist = history or {}
     order = np.argsort(cust, kind="stable")
-    bounds = np.flatnonzero(np.diff(cust[order])) + 1
-    for g in np.split(order, bounds):           # one group per customer
-        c = int(cust[g[0]])
-        seed = hist.get(c)
-        seed = (np.asarray(seed, float) if seed is not None
-                else np.empty(0))
-        ns = len(seed)
-        k = len(g)
-        n_hist[g] = ns + np.arange(k)
-        j0 = max(0, _MIN_HIST_FEAT - ns)        # first prefix with n >= 3
-        if j0 >= k:
-            continue
-        vals = np.concatenate([seed, ut[g]])
-        birth = np.concatenate([np.full(ns, -1, np.int64),
-                                np.arange(k, dtype=np.int64)])
-        o = np.argsort(vals, kind="stable")
-        vs, bs = vals[o], birth[o]
-        m = len(vals)
-        cols = np.arange(j0, k)
-        nj = ns + cols
-        vi = qf[None, :] * (nj[:, None] - 1)    # same op as np.percentile
-        lo = np.floor(vi)
-        gamma = vi - lo
-        lo_i = lo.astype(np.int64)
-        blk = max(1, _PREFIX_BLOCK_ELEMS // m)
-        out = np.empty((len(cols), len(qf)))
-        for b0 in range(0, len(cols), blk):
-            cb = cols[b0:b0 + blk]
-            # membership of each sorted value in each prefix, counted
-            # cumulatively: the rank-r member of prefix j sits at the
-            # first sorted position whose count reaches r + 1
-            count = np.cumsum(bs[:, None] < cb[None, :], axis=0,
-                              dtype=np.int32)
-            for qi in range(len(qf)):
-                rlo = lo_i[b0:b0 + blk, qi]
-                ilo = (count < (rlo + 1)[None, :].astype(np.int32)).sum(0)
-                ihi = (count < (rlo + 2)[None, :].astype(np.int32)).sum(0)
-                out[b0:b0 + blk, qi] = _np_lerp(
-                    vs[ilo], vs[ihi], gamma[b0:b0 + blk, qi])
-        percs[g[j0:]] = out
+    uniq, counts = np.unique(cust[order], return_counts=True)
+    seeds = [np.asarray(hist.get(int(c), ()), float) for c in uniq]
+    ns = np.array([len(s) for s in seeds], np.int64)
+    # every customer's sequence end to end, seed first, then its VMs in
+    # trace order: customer u holds positions [start[u], start[u] + size[u])
+    size = ns + counts
+    start = np.cumsum(size) - size
+    groups = np.split(order, np.cumsum(counts)[:-1])
+    vals = np.concatenate([part for s, g in zip(seeds, groups)
+                           for part in (s, ut[g])])
+    u = np.repeat(np.arange(len(uniq)), counts)     # customer of order[i]
+    nh = ns[u] + np.arange(n) - np.repeat(np.cumsum(counts) - counts,
+                                          counts)
+    n_hist[order] = nh
+    rows = nh >= _MIN_HIST_FEAT
+    if not rows.any():
+        return n_hist, percs
+    nj = nh[rows]
+    vi = qf[None, :] * (nj[:, None] - 1)        # same op as np.percentile
+    lo = np.floor(vi)
+    gamma = vi - lo
+    # each value's rank within its customer: sorted by (customer, value),
+    # customer u's values fill its own positions, so the ranks need only
+    # as many bits as the largest customer has values
+    owner = np.repeat(np.arange(len(uniq)), size)
+    by_value = np.lexsort((vals, owner))
+    ranks = np.empty(len(vals), np.int32)
+    ranks[by_value] = np.arange(len(vals), dtype=np.int32) \
+        - start[owner].astype(np.int32)
+    # ranks lo and lo + 1 of each VM's history [start[u], start[u] + nh)
+    k = lo.astype(np.int32)[..., None] + np.arange(2, dtype=np.int32)
+    first = start[u[rows]].astype(np.int32)[:, None, None]
+    end = first + nj.astype(np.int32)[:, None, None]
+    at = _kth_smallest(_rank_levels(ranks), np.broadcast_to(first, k.shape),
+                       np.broadcast_to(end, k.shape), k)
+    v = vals[by_value[first + at]]
+    percs[order[rows]] = _np_lerp(v[..., 0], v[..., 1], gamma)
     return n_hist, percs
 
 
@@ -286,7 +319,7 @@ def policy_decisions_compiled(vms, policy: str, control_plane=None,
     """Vectorized ``cluster_sim.policy_decisions`` (bit-exact).
 
     One batched pass replaces the per-VM control-plane walk: history
-    percentiles via sorted segment ops, one forest call for every VM's
+    percentiles from one rank structure, one forest call for every VM's
     sensitivity probability, one GBM call for every untouched quantile,
     and vectorized QoS spill/mitigation sampling.  For the ``pond``
     policy the ``control_plane``'s state is advanced to the same end
@@ -326,9 +359,12 @@ def policy_decisions_compiled(vms, policy: str, control_plane=None,
         # decide: history percentiles + LI sensitivity + UM quantile
         # predictions -> local/pool split per VM
         with rec.span("policy.decide"):
-            n_hist, percs = _prefix_percentiles(table.customer,
-                                                table.untouched,
-                                                cp.history)
+            with rec.span("policy.percentiles"):
+                n_hist, percs = _prefix_percentiles(table.customer,
+                                                    table.untouched,
+                                                    cp.history)
+            rec.count("policy.percentile_rows",
+                      int((n_hist >= _MIN_HIST_FEAT).sum()))
             if cp.li_model is not None:
                 batch = getattr(cp.li_model, "p_sensitive_batch", None)
                 p = (np.asarray(batch(table.pmu)) if batch is not None

@@ -47,18 +47,66 @@ def _tuples_soa(dec: policy_engine.PolicyDecisions):
 
 
 # ------------------------------------------------- history percentiles ----
-def test_prefix_percentiles_match_np_percentile():
-    """The sorted-segment prefix percentiles replicate np.percentile
-    (numpy's linear lerp incl. the gamma >= 0.5 branch) for every
-    prefix of every customer's history, seeds included."""
-    rng = np.random.default_rng(0)
+def _case_mixed(rng):
+    """Today's case: 12 customers, half of them seeded."""
     n = 400
-    customers = rng.integers(0, 12, n)
-    untouched = rng.random(n)
     history = {c: rng.random(rng.integers(0, 7)).tolist()
                for c in range(0, 12, 2)}       # some seeded, some not
+    return rng.integers(0, 12, n), rng.random(n), history, n
+
+
+def _case_ties(rng):
+    """Many equal values: untouched rounded to 0.05, seeds too."""
+    n = 500
+    history = {c: (np.round(rng.random(rng.integers(0, 6)) / 0.05)
+                   * 0.05).tolist() for c in range(8)}
+    return (rng.integers(0, 8, n),
+            np.round(rng.random(n) / 0.05) * 0.05, history, n)
+
+
+def _case_long_seeds(rng):
+    """Seeds longer than each customer's own VMs."""
+    n = 60
+    history = {c: rng.random(rng.integers(20, 40)).tolist()
+               for c in range(10)}
+    return rng.integers(0, 10, n), rng.random(n), history, n
+
+
+def _case_tiny_customers(rng):
+    """Customers with one or two VMs only, seeded with 0-2 values."""
+    cust = np.repeat(np.arange(40), rng.integers(1, 3, 40))
+    rng.shuffle(cust)
+    history = {c: rng.random(rng.integers(0, 3)).tolist()
+               for c in range(0, 40, 3)}
+    return cust, rng.random(len(cust)), history, len(cust)
+
+
+def _case_empty(rng):
+    return (np.zeros(0, np.int64), np.zeros(0), {1: [0.2, 0.4, 0.6]}, 0)
+
+
+def _case_one_large(rng):
+    """One customer holds 3,000 of 3,500 VMs; every row is walked."""
+    n = 3500
+    cust = np.where(np.arange(n) < 3000, 7, rng.integers(0, 20, n))
+    rng.shuffle(cust)
+    history = {7: rng.random(5).tolist(), 3: rng.random(2).tolist()}
+    return cust, rng.random(n), history, n
+
+
+@pytest.mark.parametrize("case", [_case_mixed, _case_ties, _case_long_seeds,
+                                  _case_tiny_customers, _case_empty,
+                                  _case_one_large],
+                         ids=lambda f: f.__name__[len("_case_"):])
+def test_prefix_percentiles_match_np_percentile(case):
+    """The rank-structure prefix percentiles replicate np.percentile
+    (numpy's linear lerp incl. the gamma >= 0.5 branch) for every
+    prefix of every customer's history, seeds included: the scalar
+    walk, row by row."""
+    customers, untouched, history, n = case(np.random.default_rng(0))
     n_hist, percs = policy_engine._prefix_percentiles(
         customers, untouched, history)
+    assert n_hist.shape == (n,) and percs.shape == (n, 4)
     walk: dict[int, list] = {c: list(v) for c, v in history.items()}
     for i in range(n):
         c = int(customers[i])
@@ -70,6 +118,30 @@ def test_prefix_percentiles_match_np_percentile():
             ref = np.percentile(h, [80, 90, 95, 99])
             assert percs[i].tolist() == ref.tolist()
         h.append(float(untouched[i]))
+
+
+def test_percentile_span_and_rows_counted(world):
+    """Under a live recorder the Pond policy times its percentiles in
+    ``policy.percentiles`` inside ``policy.decide``, and counts the rows
+    answered from order statistics (history of 3 or more)."""
+    from repro.core import obs
+    pop, li, um, hist, *_ = world
+    vms = pop.sample_vms(400, HORIZON, seed=5, start_id=10 ** 6)
+    table = traces.vm_table(vms)
+    n_hist, _ = policy_engine._prefix_percentiles(
+        table.customer, table.untouched, dict(hist))
+    rec = obs.Recorder()
+    with obs.use_recorder(rec):
+        policy_engine.policy_decisions_compiled(
+            vms, "pond", control_plane=_cp(li, um, hist), table=table)
+    m = rec.metrics()
+    assert m["policy.percentile_rows"] == int((n_hist >= 3).sum()) > 0
+    assert m["span.policy.percentiles.count"] == 1
+    spans = {sp["name"]: sp for sp in rec.spans()}
+    inner, outer = spans["policy.percentiles"], spans["policy.decide"]
+    assert outer["ts_ns"] <= inner["ts_ns"]
+    assert inner["ts_ns"] + inner["dur_ns"] <= \
+        outer["ts_ns"] + outer["dur_ns"]
 
 
 def test_metadata_features_compiled_bitwise(world):
