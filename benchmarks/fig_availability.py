@@ -27,7 +27,7 @@ from repro.runtime.fault import FailureSchedule
 REPAIR_S = 1800.0                      # 30 min EMC repair outage
 
 
-def _frontier(cfg, vms, dec, mtbfs, horizon, dram_fracs, backend="auto"):
+def _frontier(cfg, vms, dec, mtbfs, horizon, dram_fracs):
     """Price the (failure-rate x DRAM-savings x mitigation) grid for
     one domain size; returns per-mitigation metric arrays (K, n_cand)
     plus the schedules used."""
@@ -43,9 +43,7 @@ def _frontier(cfg, vms, dec, mtbfs, horizon, dram_fracs, backend="auto"):
     pool = np.full_like(server, np.ceil(engines[0].peak_pool_demand()))
     out = {}
     for mit in ("remigrate", "kill"):
-        r = batch.availability(server, pool, mitigation=mit,
-                               backend=backend)
-        out[mit] = r
+        out[mit] = batch.availability(server, pool, mitigation=mit)
     return out, scheds, engines, server, pool
 
 
@@ -118,13 +116,14 @@ def run(quick: bool = True) -> dict:
                                        failure_schedule=sched)
     sgb = [cfg.gb_per_core * cfg.cores_per_server * dram_fracs[-1]]
     pgb = [np.ceil(eng.peak_pool_demand())]
-    jx = eng.availability(sgb, pgb, per_failure=False)
-    orc = eng.availability(sgb, pgb, backend="oracle", per_failure=False)
-    exact = all(np.array_equal(getattr(jx, f), getattr(orc, f))
+    jx = eng.availability(sgb, pgb, "remigrate", per_failure=False)
+    orc = cluster_sim.replay_with_failures(vms, dec, cfg, sgb[0], pgb[0],
+                                           sched, "remigrate")
+    exact = all(np.asarray(getattr(jx, f)).tolist() == [getattr(orc, f)]
                 for f in ("reject_rate", "affected", "killed",
                           "remigrated", "lost_vm_minutes"))
     common.claim(res, "failure sweep bit-exact vs scalar oracle", exact,
-                 f"tightest cell, backend={'jax' if jx else '?'}")
+                 "tightest cell, XLA sweep vs replay_with_failures")
 
     d0 = res["domains"][domain_sockets[0]]
     hi_rate, lo_rate = 0, len(mtbfs) - 1      # mtbfs sorted ascending

@@ -14,47 +14,38 @@ This module splits that work into a compile phase and a batched sweep:
   ``(time, kind)`` exactly like the scalar oracle, plus per-VM payload
   vectors (cores, local_gb, pool_gb, fallback mem_gb).
 
-* **Reference trajectories** — a candidate's replay only departs from a
-  *looser* replay at the first event where the candidate's capacity
-  binds.  The engine therefore builds (and caches) reference
-  trajectories: the cores-only replay (memory unbounded) for batches that
-  vary server_gb, and per-server-size replays at (server_gb, infinite
-  pool) for batches that vary pool_gb at few distinct server sizes — the
-  shape of the provisioning search.  Each trajectory records per-event
-  admission thresholds (the least capacity keeping the event admissible),
-  cumulative usage snapshots every ``SNAP`` events, and its reject count.
+* **Reference trajectories** — ``pool_search_batched`` brackets each
+  server size's pool search with the engine's cached replay at
+  (server_gb, infinite pool): its peak pool demand is always feasible
+  and its reject count decides whether any pool size is.
 
-* **Divergence windows** — one vectorized compare against the thresholds
-  yields each candidate's first violation event.  Never-diverging
-  candidates inherit the trajectory's reject count for free; the rest
-  enter the batched sweep in at most ``MAX_WAVES`` waves, their state
-  reconstructed bit-exactly from the snapshots (VM memory quantities are
-  integral GBs, so cumulative sums reproduce the oracle's floats; with
-  non-integral decisions the shortcut is disabled and every candidate
-  simulates from event 0 — still exact, just slower).
+* **XLA sweep (integral input)** — because every VM memory quantity is
+  an integral GB, admission tests like ``free_mem >= local_gb`` are
+  exactly ``used_mem + local_gb <= floor(server_gb)`` over int32, so the
+  whole batched sweep compiles to one ``lax.scan`` (a Pallas kernel on
+  a TPU) in JAX's default x32 mode and still matches the float64 oracle
+  bit-for-bit.  Placement state lives in a slot array sized by PEAK
+  CONCURRENCY (VM slots are reused after departure) and is updated with
+  leading-axis dynamic slices, so the scan carry stays small and in
+  place.  Event streams, servers and groups pad to fixed buckets so
+  recompiles are rare.  This path needs jax: without it, integral input
+  raises a ``RuntimeError`` rather than falling back.
 
-* **XLA backend (default)** — because every VM memory quantity is an
-  integral GB, admission tests like ``free_mem >= local_gb`` are exactly
-  ``used_mem + local_gb <= floor(server_gb)`` over int32, so the whole
-  batched sweep compiles to one ``lax.scan`` in JAX's default x32 mode
-  and still matches the float64 oracle bit-for-bit.  Placement state
-  lives in a slot array sized by PEAK CONCURRENCY (VM slots are reused
-  after departure) and is updated with leading-axis dynamic slices, so
-  the scan carry stays small and in place.  Event streams, servers and
-  groups pad to fixed buckets so recompiles are rare.
-
-* **numpy backend (fallback / reference)** — the live batch carries
-  placement state as a packed ``(n_live, n_servers + 1, 3)`` array (free
-  cores / free local GB / free pool GB mirrored per server; the +1
-  column is an always-infeasible dummy absorbing ragged pool groups).
-  One fused ``>=``-compare + ``all`` answers cores, memory and pool
-  admission for every (candidate, server) pair at once.  VMs whose
-  arrival fast-pathed on every live candidate are tracked in a "clean"
-  set so their departures skip migration/unplaced handling.  Searches
-  only need feasibility (rate <= tol), so they pass ``reject_cap``:
-  candidates whose reject count exceeds the cap are compacted out
-  mid-sweep (reported rate is the lower bound ``(cap + 1) / n``), and
-  event ranges with no live candidate are skipped.
+* **numpy sweep (non-integral input)** — a decision or VM size that is
+  not an integral GB (an ingested trace with fractional ``mem_gb``)
+  would round in the int32 tests, so the engines pick a float64 sweep
+  from the input instead; there is no switch.  The live batch carries
+  placement state as a packed ``(n_live, n_servers + 1, 3)`` array
+  (free cores / free local GB / free pool GB mirrored per server; the
+  +1 column is an always-infeasible dummy absorbing ragged pool
+  groups).  One fused ``>=``-compare + ``all`` answers cores, memory
+  and pool admission for every (candidate, server) pair at once.  VMs
+  whose arrival fast-pathed on every live candidate are tracked in a
+  "clean" set so their departures skip migration/unplaced handling.
+  Searches only need feasibility (rate <= tol), so they pass
+  ``reject_cap``: candidates whose reject count exceeds the cap are
+  compacted out mid-sweep (reported rate is the lower bound
+  ``(cap + 1) / n``).  Each such sweep counts ``replay.backend_numpy``.
 
 With ``reject_cap=None`` the sweep is semantically EXACT with respect to
 the scalar oracle: same event order, same best-fit argmin tie-break
@@ -68,6 +59,12 @@ pricing whole dyadic probe trees per sweep; ``pool_search_batched`` runs
 all server-size points' pool searches in lockstep, bracketed for free by
 each size's infinite-pool trajectory and warm-started from neighbors
 (required pool is monotone non-increasing in server_gb).
+
+* **Two sweep drivers** — ``CompiledReplay`` and ``CompiledReplayStream``
+  compile one trace; the sweeps run in the two batch engines below.  A
+  single engine's ``reject_rates``, ``availability`` and
+  ``reject_rates_fleet`` price it as the one row of a K=1 batch (built
+  on first use), so every dispatch of a family goes through one driver.
 
 * **Trace batch axis** — ``CompiledReplayBatch`` stacks K compiled
   traces (synthetic seeds or ingested real traces, see
@@ -113,7 +110,8 @@ each size's infinite-pool trajectory and warm-started from neighbors
   trace-batch axis (or, when K < n_devices and for single traces, the
   candidate-lane axis) is partitioned across a 1-D
   ``jax.sharding.Mesh`` with ``shard_map`` inside the same jitted
-  scans.  The partitioned axes are embarrassingly parallel — no
+  scans (the streaming batch splits lanes only for a single trace).
+  The partitioned axes are embarrassingly parallel — no
   collectives — so sharded results are bit-exact (``==``) vs the
   single-device path; fewer than two resolved devices degrades to the
   unsharded sweep.  CPU-only hosts: export
@@ -146,25 +144,25 @@ ARRIVE, DEPART, MIGRATE = (sweep_core.ARRIVE, sweep_core.DEPART,
                            sweep_core.MIGRATE)
 PAD = sweep_core.PAD  # no-op event kind padding the XLA event stream
 FAIL, RECOVER = sweep_core.FAIL, sweep_core.RECOVER  # §4.2 domain events
-MAX_WAVES = 12        # state-rebuild budget per sweep (numpy backend)
-MAX_TRAJS = 16        # per-server-size trajectories per sweep
-SNAP = 64             # snapshot stride (events) in trajectories
 _INF = np.inf
 _I16_SAFE = sweep_core.I16_SAFE   # re-export: boundary tests pin it
 
 
-def _auto_backend(backend: str, exact: bool,
-                  fallback: str = "numpy") -> str:
-    """Resolve ``backend="auto"``: the XLA sweep when jax is importable
-    and every decision is an integral GB (``exact``), else ``fallback``.
-    Each fallback bumps the ``replay.backend_numpy`` counter, so a run
-    that expects the device can see that a sweep never reached it."""
-    if backend != "auto":
-        return backend
-    if exact and sweep_core.jax_importable():
-        return "jax"
+def _on_device(exact: bool) -> bool:
+    """The sweep path, chosen from the input: the XLA/Pallas sweep when
+    every quantity is an integral GB (``exact``), else the float64 numpy
+    sweep (the scalar oracle for availability).  Each fallback bumps the
+    ``replay.backend_numpy`` counter, so a run that expects the device
+    can see that a sweep never reached it.  Integral input needs jax:
+    without it this raises instead of falling back."""
+    if exact:
+        if not sweep_core.jax_importable():
+            raise RuntimeError(
+                "integral-GB input is replayed by the jax sweep, and jax "
+                "cannot be imported")
+        return True
     obs.get_recorder().count("replay.backend_numpy")
-    return fallback
+    return False
 
 
 # ----------------------------------------------------- decision ingest -----
@@ -227,6 +225,14 @@ def stats_snapshot() -> dict:
     return _STATS.as_dict()
 
 
+def _count_sweep(t0: float, events: int, candidate_events: int) -> None:
+    """Fold one finished sweep, started at ``t0``, into ``_STATS``."""
+    _STATS.sweeps += 1
+    _STATS.events += events
+    _STATS.candidate_events += candidate_events
+    _STATS.wall_s += time.perf_counter() - t0
+
+
 # --------------------------------------------------------------- compile ---
 def compiled_arrive_depart(vms):
     """Arrival/departure events as sorted arrays ``(time, kind, vm_index)``.
@@ -246,28 +252,12 @@ def compiled_arrive_depart(vms):
 
 @dataclasses.dataclass
 class _Trajectory:
-    """One reference replay of the compiled trace.
-
-    ``server_gb is None``: cores-only replay (memory/pool unbounded) —
-    ``need_srv[e]``/``need_pool[e]`` are the least server/pool capacity
-    keeping event ``e`` admissible on this path.  ``server_gb`` set:
-    the oracle replay at (server_gb, infinite pool) — only ``need_pool``
-    is meaningful; candidates must share this exact server_gb.
-    Snapshots record state BEFORE events 0, SNAP, 2*SNAP, ...
-    """
-    server_gb: float | None
-    need_srv: np.ndarray          # (E,)
+    """One reference replay of the compiled trace at (server_gb,
+    infinite pool): ``need_pool[e]`` is the least pool capacity keeping
+    event ``e`` admissible on this path, ``total_rejects`` its reject
+    count."""
     need_pool: np.ndarray         # (E,)
     total_rejects: int
-    snap_rejects: np.ndarray      # (n_snap,) rejects before snapshot event
-    snap_cores: np.ndarray        # (n_snap, S) free cores
-    snap_mem: np.ndarray          # (n_snap, S) local GB in use
-    snap_pool: np.ndarray         # (n_snap, G) pool GB in use
-    srv: np.ndarray               # (V,) placement (-1 rejected/never)
-    arr_idx: np.ndarray           # (V,) arrival event index
-    dep_idx: np.ndarray           # (V,) departure event index
-    mig: np.ndarray               # (V,) departs-as-all-local flag
-    mig_idx: np.ndarray           # (V,) event index the flag was set
 
 
 @dataclasses.dataclass
@@ -402,10 +392,11 @@ class CompiledReplay:
         #: VM-minutes-lost clock, quantized exactly like the oracle
         self._dep_min = np.floor(dep_a / 60.0).astype(np.int32)
         self.n_events = len(self._ev_kind)
-        self._trajs: dict[float | None, _Trajectory] = {}
+        self._trajs: dict[float, _Trajectory] = {}
         self._jax_ev = None
         self._jax_ev_fail = None
         self._peak_pool = None
+        self._batch = None
 
     def peak_pool_demand(self) -> float:
         """Cheap upper bound on the pool any candidate can ever need.
@@ -428,7 +419,8 @@ class CompiledReplay:
 
     # ------------------------------------------------------ XLA compile --
     def _jax_events(self):
-        """Slot-mapped, padded int32 event arrays for the XLA sweep.
+        """Slot-mapped, padded int32 host event arrays for the XLA
+        sweep (the batch engines stack and upload them).
 
         VMs are assigned reusable slots (freed on departure), so the
         per-candidate placement state is sized by PEAK CONCURRENCY, not
@@ -449,7 +441,7 @@ class CompiledReplay:
         def pad(vals, fill):
             out = np.full(e_pad, fill, np.int32)
             out[:n_ev] = vals
-            return sweep_core.device_put(out)
+            return out
 
         rec = obs.get_recorder()
         if rec.enabled:
@@ -463,8 +455,7 @@ class CompiledReplay:
                pad(np.asarray(self._mem, np.int32)[vmx], 0))
         group_np = np.zeros(s_pad, np.int32)
         group_np[:n_srv] = self.group_of
-        self._jax_ev = (evs, sweep_core.device_put(group_np), n_slots,
-                        s_pad, g_pad)
+        self._jax_ev = (evs, group_np, n_slots, s_pad, g_pad)
         return self._jax_ev
 
     def _pick_state_dtype(self, sgb_i: np.ndarray,
@@ -485,7 +476,7 @@ class CompiledReplay:
         if self._jax_ev_fail is not None:
             return self._jax_ev_fail
         evs, group_of, n_slots, s_pad, g_pad = self._jax_events()
-        e_pad = int(np.asarray(evs[0]).shape[0])
+        e_pad = evs[0].shape[0]
         kind = np.asarray(self._ev_kind)
         x = np.zeros(e_pad, np.int32)
         dmn = np.full(e_pad, -1, np.int32)
@@ -496,15 +487,21 @@ class CompiledReplay:
             np.where(kind == FAIL,
                      np.floor(self.ev_time / 60.0).astype(np.int32), 0))
         dmn[:n_ev] = self._ev_dom
-        evs8 = evs + (sweep_core.device_put(x),
-                      sweep_core.device_put(dmn))
+        evs8 = evs + (x, dmn)
         self._jax_ev_fail = (evs8, group_of, n_slots, s_pad, g_pad)
         return self._jax_ev_fail
+
+    def _as_batch(self) -> "CompiledReplayBatch":
+        """This engine as the one row of a :class:`CompiledReplayBatch`,
+        built on first use: the batch's drivers run every XLA sweep of
+        a single trace."""
+        if self._batch is None:
+            self._batch = CompiledReplayBatch([self])
+        return self._batch
 
     @obs.traced("replay.availability")
     def availability(self, server_gb, pool_gb,
                      mitigation: str = "remigrate",
-                     backend: str = "auto",
                      state_dtype: str | None = None,
                      per_failure: bool = True) -> "AvailabilityResult":
         """Price the merged failure schedule: reject rates WITH the
@@ -515,12 +512,13 @@ class CompiledReplay:
         :meth:`reject_rates`.  ``mitigation`` picks the blast-radius
         policy (``"remigrate"`` pulls affected pool into host-local
         DRAM where the server's free memory allows, all-or-nothing per
-        server; ``"kill"`` terminates every affected VM).  The jax
-        backend resolves failures inside the same scan step
-        (``sweep_core.build_fail_sweep``); ``backend="oracle"`` (also
-        the non-jax/non-integral fallback) loops the scalar
-        blast-radius oracle ``cluster_sim.replay_with_failures`` —
-        bit-exact either way (``tests/test_failures.py``).
+        server; ``"kill"`` terminates every affected VM).  On integral
+        input the failures resolve inside the same scan step
+        (``sweep_core.build_fail_sweep``, run as row 0 of a K=1
+        :class:`CompiledReplayBatch`); on non-integral input the
+        engine loops the scalar blast-radius oracle
+        ``cluster_sim.replay_with_failures`` — bit-exact either way
+        (``tests/test_failures.py``).
 
         Returns an :class:`AvailabilityResult`; with
         ``per_failure=True`` it includes the ``(n_failures, n_cand)``
@@ -533,66 +531,26 @@ class CompiledReplay:
         server_gb = np.atleast_1d(np.asarray(server_gb, float))
         pool_gb = np.atleast_1d(np.asarray(pool_gb, float))
         server_gb, pool_gb = np.broadcast_arrays(server_gb, pool_gb)
-        t0 = time.perf_counter()
-        backend = _auto_backend(backend, self._exact, "oracle")
-        if backend == "jax":
-            res = self._availability_jax(server_gb, pool_gb, mitigation,
-                                         state_dtype, per_failure)
-        else:
-            res = self._availability_oracle(server_gb, pool_gb,
-                                            mitigation, per_failure)
-        _STATS.sweeps += 1
-        _STATS.events += self.n_events
-        _STATS.candidate_events += self.n_events * len(server_gb)
-        _STATS.wall_s += time.perf_counter() - t0
-        return res
-
-    def _availability_jax(self, server_gb, pool_gb, mitigation,
-                          state_dtype, per_failure):
-        evs, group_of, n_slots, s_pad, g_pad = self._jax_events_fail()
-        n0 = len(server_gb)
-        sgb_i, pgb_i = sweep_core.quantize_capacities(server_gb, pool_gb)
-        dt_name = state_dtype or self._pick_state_dtype(sgb_i, pgb_i)
-        np_dt = sweep_core.state_np_dtype(dt_name)
-        sweep = sweep_core.get_fail_sweep(dt_name, mitigation,
-                                          with_dist=per_failure)
-        rec = obs.get_recorder()
-        kind = np.asarray(self._ev_kind)
-        fail_pos = np.flatnonzero(kind == FAIL)
-        out = {k: np.empty(n0, np.int64) for k in
-               ("rejects", "affected", "killed", "remig", "lost")}
-        dist = (np.empty((len(fail_pos), n0), np.int64)
-                if per_failure else None)
-        for lo, hi, width in sweep_core.candidate_chunks(n0):
-            sgb, pgb = sweep_core.lane_capacities(sgb_i, pgb_i, lo, hi,
-                                                  width, np_dt)
-            fc0, um0, up0, slots0, _ = sweep_core.init_state(
-                width, self.n_servers, self.cores_per_server, s_pad,
-                g_pad, n_slots, np_dt)
-            fstate = sweep_core.init_fail_state(n_slots, g_pad)
-            args = tuple(sweep_core.device_put(a) for a in
-                         (fc0, um0, up0, slots0) + fstate + (sgb, pgb))
-            with rec.span("replay.compute"):
-                res = sweep(evs, group_of, *args)
-                for key, a in zip(("rejects", "affected", "killed",
-                                   "remig", "lost"), res[:5]):
-                    out[key][lo:hi] = np.asarray(a)[:hi - lo]
-                if per_failure:
-                    dist[:, lo:hi] = \
-                        np.asarray(res[5])[fail_pos, :hi - lo]
-            rec.count("sweep.steps", self.n_events)
-        return AvailabilityResult(
-            reject_rate=out["rejects"] / max(self.n_vms, 1),
-            affected=out["affected"], killed=out["killed"],
-            remigrated=out["remig"], lost_vm_minutes=out["lost"],
-            n_failures=len(fail_pos), affected_per_failure=dist,
-            mitigation=mitigation)
+        if not _on_device(self._exact):
+            return self._availability_oracle(server_gb, pool_gb,
+                                             mitigation, per_failure)
+        res = self._as_batch()._availability(
+            server_gb[None], pool_gb[None], mitigation, state_dtype,
+            per_failure)
+        return dataclasses.replace(
+            res, reject_rate=res.reject_rate[0], affected=res.affected[0],
+            killed=res.killed[0], remigrated=res.remigrated[0],
+            lost_vm_minutes=res.lost_vm_minutes[0],
+            n_failures=int(res.n_failures[0]),
+            affected_per_failure=res.affected_per_failure[0]
+            if per_failure else None)
 
     def _availability_oracle(self, server_gb, pool_gb, mitigation,
                              per_failure):
-        """Scalar-oracle fallback (no jax / non-integral decisions):
-        one ``cluster_sim.replay_with_failures`` call per candidate."""
+        """Scalar-oracle sweep for non-integral decisions: one
+        ``cluster_sim.replay_with_failures`` call per candidate."""
         from repro.core import cluster_sim  # deferred: cyclic at import
+        t0 = time.perf_counter()
         decisions = (self._decisions_src.as_vmdecisions()
                      if hasattr(self._decisions_src, "as_vmdecisions")
                      else self._decisions_src)
@@ -616,81 +574,22 @@ class CompiledReplay:
             if per_failure:
                 dist[:, i] = r.affected_per_failure
             n_failures = r.n_failures
+        _count_sweep(t0, self.n_events, self.n_events * n0)
         return AvailabilityResult(
             reject_rate=rates, affected=out["affected"],
             killed=out["killed"], remigrated=out["remig"],
             lost_vm_minutes=out["lost"], n_failures=n_failures,
             affected_per_failure=dist, mitigation=mitigation)
 
-    def _reject_rates_jax(self, server_gb, pool_gb,
-                          state_dtype: str | None = None,
-                          devices=None) -> np.ndarray:
-        """XLA sweep over the whole batch, in candidate chunks of 16/96.
-
-        Carry state packs to int16 when capacities permit (half the
-        sweep's memory traffic) and falls back to int32 otherwise;
-        ``state_dtype`` forces one packing (testing hook).  ``devices``
-        shards the candidate-lane axis over a device mesh (events
-        replicated, per-lane state split), bit-exact vs single-device.
-        """
-        evs, group_of, n_slots, s_pad, g_pad = self._jax_events()
-        n0 = len(server_gb)
-        rejects = np.empty(n0, np.int64)
-        sgb_i, pgb_i = sweep_core.quantize_capacities(server_gb, pool_gb)
-        dt_name = state_dtype or self._pick_state_dtype(sgb_i, pgb_i)
-        np_dt = sweep_core.state_np_dtype(dt_name)
-        devs = sweep_core.resolve_devices(devices)
-        rec = obs.get_recorder()
-        placed = {}                    # per-mesh replicated event tensors
-        for lo, hi, width in sweep_core.candidate_chunks(n0):
-            mesh = sh_lane = sh_slot = None
-            evs_m, group_m = evs, group_of
-            if devs is not None:
-                n_lane = sweep_core.lane_shard_count(width, len(devs))
-                if n_lane >= 2:
-                    mesh = sweep_core.shard_mesh(devs[:n_lane])
-                    sh_lane = sweep_core.named_sharding(mesh, "shard")
-                    sh_slot = sweep_core.named_sharding(mesh, None,
-                                                        "shard")
-                    if mesh not in placed:
-                        rep = sweep_core.named_sharding(mesh)
-                        placed[mesh] = (
-                            tuple(sweep_core.device_put(np.asarray(a),
-                                                        rep)
-                                  for a in evs),
-                            sweep_core.device_put(np.asarray(group_of),
-                                                  rep))
-                    evs_m, group_m = placed[mesh]
-            sweep = sweep_core.get_sweep(dt_name, mesh=mesh,
-                                         shard_axis="lane")
-            sgb, pgb = sweep_core.lane_capacities(sgb_i, pgb_i, lo, hi,
-                                                  width, np_dt)
-            fc0, um0, up0, slots0, _ = sweep_core.init_state(
-                width, self.n_servers, self.cores_per_server, s_pad,
-                g_pad, n_slots, np_dt)
-            args = tuple(sweep_core.device_put(a, sh) for a, sh in zip(
-                (fc0, um0, up0, slots0, sgb, pgb),
-                (sh_lane, sh_lane, sh_lane, sh_slot, sh_lane, sh_lane)))
-            with rec.span("replay.compute"):
-                out = sweep(evs_m, group_m, *args)
-                rejects[lo:hi] = np.asarray(out)[:hi - lo]
-            rec.count("sweep.steps", self.n_events)
-            if rec.enabled:
-                _count_scanned(rec, hi - lo, self._ev_kind.count(ARRIVE),
-                               self.n_events, self.n_servers,
-                               self.n_groups, 2)
-        return rejects / max(self.n_vms, 1)
-
     # --------------------------------------------- reference trajectories --
-    def _trajectory(self, server_gb: float | None) -> _Trajectory:
-        """Replay once at (server_gb or infinity, infinite pool), recording
-        admission thresholds + strided state snapshots (lean Python loop;
-        cached, so each trajectory is built one time per engine)."""
-        key = None if server_gb is None else float(server_gb)
+    def _trajectory(self, server_gb: float) -> _Trajectory:
+        """Replay once at (server_gb, infinite pool), recording each
+        event's pool threshold (lean Python loop; cached, so each
+        trajectory is built one time per engine)."""
+        key = float(server_gb)
         cached = self._trajs.get(key)
         if cached is not None:
             return cached
-        bound = key is not None
         n_srv, n_vms, n_ev = self.n_servers, self.n_vms, self.n_events
         group_of = self.group_of.tolist()
         cores_of, mem_of = self._cores, self._mem
@@ -700,45 +599,22 @@ class CompiledReplay:
         fc = [self.cores_per_server] * n_srv
         um = [0.0] * n_srv
         up = [0.0] * self.n_groups
-        n_snap = n_ev // SNAP + 1
-        need_srv = np.zeros(n_ev)
         need_pool = np.zeros(n_ev)
-        snap_rejects = np.zeros(n_snap, np.int64)
-        snap_cores = np.empty((n_snap, n_srv))
-        snap_mem = np.empty((n_snap, n_srv))
-        snap_pool = np.empty((n_snap, self.n_groups))
         srv = np.full(n_vms, -1, np.int64)
-        arr_idx = np.full(n_vms, n_ev, np.int64)
-        dep_idx = np.full(n_vms, n_ev, np.int64)
         mig = np.zeros(n_vms, bool)
-        mig_idx = np.full(n_vms, n_ev, np.int64)
         live = [False] * n_vms
         rejects = 0
 
         for e in range(n_ev):
-            if e % SNAP == 0:
-                i = e // SNAP
-                snap_cores[i] = fc
-                snap_mem[i] = um
-                snap_pool[i] = up
-                snap_rejects[i] = rejects
             v = ev_vm[e]
             kind = ev_kind[e]
             if kind == ARRIVE:
-                arr_idx[v] = e
                 c, l = cores_of[v], local_of[v]
                 best, bv = -1, _INF
-                if bound:
-                    sgb = key
-                    for s in range(n_srv):      # best fit, first min
-                        f = fc[s]
-                        if f >= c and sgb - um[s] >= l and f < bv:
-                            best, bv = s, f
-                else:
-                    for s in range(n_srv):
-                        f = fc[s]
-                        if f >= c and f < bv:
-                            best, bv = s, f
+                for s in range(n_srv):          # best fit, first min
+                    f = fc[s]
+                    if f >= c and key - um[s] >= l and f < bv:
+                        best, bv = s, f
                 if best >= 0:
                     g = group_of[best]
                     p = pool_of[v]
@@ -747,29 +623,24 @@ class CompiledReplay:
                     up[g] += p
                     srv[v] = best
                     live[v] = True
-                    need_srv[e] = um[best]
                     need_pool[e] = up[g]
                     continue
-                if bound:
-                    # pool can't help here (it is infinite on this path):
-                    # the oracle's all-local fallback
-                    m = mem_of[v]
-                    for s in range(n_srv):
-                        f = fc[s]
-                        if f >= c and sgb - um[s] >= m and f < bv:
-                            best, bv = s, f
-                    if best >= 0:
-                        fc[best] -= c
-                        um[best] += m
-                        srv[v] = best
-                        live[v] = True
-                        mig[v] = True           # departs as all-local
-                        mig_idx[v] = e
-                        need_srv[e] = um[best]
-                        continue
-                rejects += 1                    # binds for every candidate
+                # pool can't help here (it is infinite on this path):
+                # the oracle's all-local fallback
+                m = mem_of[v]
+                for s in range(n_srv):
+                    f = fc[s]
+                    if f >= c and key - um[s] >= m and f < bv:
+                        best, bv = s, f
+                if best >= 0:
+                    fc[best] -= c
+                    um[best] += m
+                    srv[v] = best
+                    live[v] = True
+                    mig[v] = True               # departs as all-local
+                    continue
+                rejects += 1
             elif kind == DEPART:
-                dep_idx[v] = e
                 if not live[v]:
                     continue
                 live[v] = False
@@ -780,29 +651,16 @@ class CompiledReplay:
                 else:
                     um[s] -= local_of[v]
                     up[group_of[s]] -= pool_of[v]
-            elif kind == MIGRATE:               # MIGRATE: pool -> local if
-                if not live[v] or mig[v]:       # the host has local room
-                    if live[v] and mig[v]:
-                        # oracle quirk: a fallback-placed VM can still be
-                        # "migrated" — it moves pool_gb mem->pool
-                        s = int(srv[v])
-                        p = pool_of[v]
-                        if not bound or key - um[s] >= p:
-                            um[s] += p
-                            up[group_of[s]] -= p
-                            need_srv[e] = um[s]
-                    continue
-                s = int(srv[v])
+            elif kind == MIGRATE and live[v]:   # pool -> local if the
+                s = int(srv[v])                 # host has local room
                 p = pool_of[v]
-                if not bound or key - um[s] >= p:
+                # (oracle quirk: a fallback-placed VM can still be
+                # "migrated" — it moves pool_gb mem->pool)
+                if key - um[s] >= p:
                     um[s] += p
                     up[group_of[s]] -= p
                     mig[v] = True
-                    mig_idx[v] = e
-                    need_srv[e] = um[s]
-        traj = _Trajectory(key, need_srv, need_pool, rejects, snap_rejects,
-                           snap_cores, snap_mem, snap_pool, srv, arr_idx,
-                           dep_idx, mig, mig_idx)
+        traj = _Trajectory(need_pool, rejects)
         self._trajs[key] = traj
         return traj
 
@@ -810,29 +668,27 @@ class CompiledReplay:
     @obs.traced("replay.reject_rates")
     def reject_rates(self, server_gb, pool_gb,
                      reject_cap: int | None = None,
-                     backend: str = "auto",
                      state_dtype: str | None = None,
                      devices=None) -> np.ndarray:
         """Reject fraction for each (server_gb, pool_gb) candidate.
 
-        ``devices`` shards the XLA backend's candidate-lane axis over a
-        JAX device mesh (``"all"``, an int, or an explicit device
+        Accepts scalars or broadcastable 1-D arrays; one event sweep
+        prices the whole batch, bit-exact vs the scalar oracle.  On
+        integral-GB input the sweep is the XLA integer sweep, run as
+        row 0 of a K=1 :class:`CompiledReplayBatch`; its carry packs to
+        int16 when the candidate capacities (plus payload headroom)
+        permit — half the memory traffic — and falls back to int32
+        automatically; ``state_dtype`` ("int16"/"int32") forces one
+        packing for tests.  ``devices`` shards its candidate-lane axis
+        over a JAX device mesh (``"all"``, an int, or an explicit device
         list — see :func:`sweep_core.resolve_devices`), bit-exact vs
-        single-device; the numpy backend ignores it.
-
-        Accepts scalars or broadcastable 1-D arrays; one event sweep prices
-        the whole batch.  ``backend="auto"`` uses the XLA integer sweep
-        when jax is importable and the decisions are integral GBs
-        (bit-exact either way), falling back to the numpy
-        divergence-window sweep.  The XLA carry packs to int16 when the
-        candidate capacities (plus payload headroom) permit — half the
-        memory traffic — and falls back to int32 automatically;
-        ``state_dtype`` ("int16"/"int32") forces one packing for tests.
-        With ``reject_cap`` set, the numpy backend drops candidates
-        exceeding the cap mid-sweep and reports the lower bound
-        ``(reject_cap + 1) / n_vms`` — only valid for feasibility tests
-        against a tolerance below that bound (the XLA backend always
-        returns exact rates, which satisfy the same contract).
+        single-device.  Non-integral input runs the float64 numpy sweep
+        instead, which ignores both.  With ``reject_cap`` set, the
+        numpy sweep drops candidates exceeding the cap mid-sweep and
+        reports the lower bound ``(reject_cap + 1) / n_vms`` — only
+        valid for feasibility tests against a tolerance below that
+        bound (the XLA sweep always returns exact rates, which satisfy
+        the same contract).
 
         Usage (price a 9-point frontier in one sweep)::
 
@@ -840,103 +696,41 @@ class CompiledReplay:
             rates = eng.reject_rates(np.linspace(200., 400., 9),
                                      np.linspace(0., 800., 9))
         """
-        t0 = time.perf_counter()
         server_gb = np.atleast_1d(np.asarray(server_gb, float))
         pool_gb = np.atleast_1d(np.asarray(pool_gb, float))
         server_gb, pool_gb = np.broadcast_arrays(server_gb, pool_gb)
+        if not self.n_events:
+            return np.zeros(len(server_gb))
+        if not _on_device(self._exact):
+            return self._reject_rates_numpy(server_gb, pool_gb, reject_cap)
+        return self._as_batch()._reject_rates(
+            server_gb[None], pool_gb[None], state_dtype, devices)[0]
+
+    def _reject_rates_numpy(self, server_gb, pool_gb,
+                            reject_cap: int | None = None) -> np.ndarray:
+        """The float64 sweep of non-integral input: every candidate
+        replays from event 0 in one vectorized pass (see the module
+        docstring's numpy sweep)."""
+        t0 = time.perf_counter()
         n0 = len(server_gb)
         n_srv, n_vms, n_ev = self.n_servers, self.n_vms, self.n_events
         denom = max(n_vms, 1)
         if not n_ev:
             return np.zeros(n0)
-        if _auto_backend(backend, self._exact) == "jax":
-            rates = self._reject_rates_jax(server_gb, pool_gb,
-                                           state_dtype=state_dtype,
-                                           devices=devices)
-            _STATS.sweeps += 1
-            _STATS.events += n_ev
-            _STATS.candidate_events += n_ev * n0
-            _STATS.wall_s += time.perf_counter() - t0
-            return rates
         rates = np.empty(n0)
-
-        # pick reference trajectories + first-divergence event per
-        # candidate; never-diverging candidates are priced for free
-        entries: list[tuple[int, _Trajectory | None, np.ndarray]] = []
-        if not (self._exact and n_ev):
-            entries.append((0, None, np.arange(n0)))
-            todo = np.arange(n0)
-        else:
-            uniq = np.unique(server_gb)
-            # per-size trajectories pay off only for pool-varying batches
-            # (fewer sizes than candidates) or when every size's
-            # trajectory is already cached; a server-varying batch uses
-            # the single cores-only reference instead
-            per_sgb = len(uniq) <= MAX_TRAJS and (
-                len(uniq) < n0
-                or all(float(s) in self._trajs for s in uniq))
-            divs = np.empty(n0, np.int64)
-            diverges = np.empty(n0, bool)
-            trajs: list[tuple[_Trajectory, np.ndarray]] = []
-            if per_sgb:       # pool-varying batch at few server sizes
-                for sgb in uniq:
-                    idx = np.flatnonzero(server_gb == sgb)
-                    traj = self._trajectory(float(sgb))
-                    viol = traj.need_pool[:, None] > pool_gb[idx][None, :]
-                    dv = viol.any(axis=0)
-                    divs[idx] = np.where(dv, viol.argmax(axis=0), n_ev)
-                    diverges[idx] = dv
-                    trajs.append((traj, idx))
-            else:             # server-varying batch: cores-only reference
-                traj = self._trajectory(None)
-                viol = (traj.need_srv[:, None] > server_gb[None, :]) | \
-                       (traj.need_pool[:, None] > pool_gb[None, :])
-                diverges = viol.any(axis=0)
-                divs = np.where(diverges, viol.argmax(axis=0), n_ev)
-                trajs.append((traj, np.arange(n0)))
-            for traj, idx in trajs:
-                rates[idx[~diverges[idx]]] = traj.total_rejects / denom
-            todo = np.flatnonzero(diverges)
-            if todo.size:
-                # entry waves, earliest divergence first; entry events are
-                # snapshot-aligned (entering early is exact)
-                order = todo[np.argsort(divs[todo], kind="stable")]
-                traj_of = np.empty(n0, np.int64)
-                for ti, (_, idx) in enumerate(trajs):
-                    traj_of[idx] = ti
-                for chunk in np.array_split(
-                        order, min(MAX_WAVES, len(order))):
-                    if not len(chunk):
-                        continue
-                    ev = int(divs[chunk[0]]) // SNAP * SNAP
-                    for ti in np.unique(traj_of[chunk]):
-                        g = chunk[traj_of[chunk] == ti]
-                        entries.append((ev, trajs[ti][0], g))
-                entries.sort(key=lambda w: w[0])
-                merged: list[tuple[int, _Trajectory | None, np.ndarray]] = []
-                for ev, traj, g in entries:   # merge same (event, traj)
-                    if merged and merged[-1][0] == ev \
-                            and merged[-1][1] is traj:
-                        merged[-1] = (ev, traj,
-                                      np.concatenate([merged[-1][2], g]))
-                    else:
-                        merged.append((ev, traj, g))
-                entries = merged
-
-        if not todo.size:
-            _STATS.sweeps += 1
-            _STATS.events += n_ev
-            _STATS.wall_s += time.perf_counter() - t0
-            return rates
         if reject_cap is not None:      # default for dropped candidates
-            rates[todo] = (reject_cap + 1) / denom
+            rates[:] = (reject_cap + 1) / denom
 
-        free = np.empty((0, n_srv + 1, 3))
-        placed = np.empty((0, n_vms), np.int32)
-        migrated = np.empty((0, n_vms), bool)
-        rejects = np.empty(0, np.int64)
-        alive = np.empty(0, np.int64)
-        cidx = np.empty(0, np.int64)
+        free = np.empty((n0, n_srv + 1, 3))
+        free[:, :n_srv, 0] = self.cores_per_server
+        free[:, :n_srv, 1] = server_gb[:, None]
+        free[:, :n_srv, 2] = pool_gb[:, None]
+        free[:, n_srv, :] = -_INF
+        placed = np.full((n0, n_vms), -1, np.int32)
+        migrated = np.zeros((n0, n_vms), bool)
+        rejects = np.zeros(n0, np.int64)
+        alive = np.arange(n0)
+        cidx = np.arange(n0)
         clean: set = set()              # vms fast-pathed on every live row
         gcols = self._gcols
         vec3s, vec2s = self._vec3, self._vec2
@@ -944,51 +738,13 @@ class CompiledReplay:
         local_of, pool_of = self._local, self._pool
         ev_kind, ev_vm = self._ev_kind, self._ev_vm
         cand_events = 0
-        wi = 0
-        e = entries[0][0]
 
-        while e < n_ev:
-            while wi < len(entries) and entries[wi][0] == e:
-                ev, traj, g = entries[wi]
-                wi += 1
-                k = len(g)
-                base = np.empty((k, n_srv + 1, 3))
-                if traj is None:                # virgin start at event 0
-                    base[:, :n_srv, 0] = self.cores_per_server
-                    base[:, :n_srv, 1] = server_gb[g][:, None]
-                    base[:, :n_srv, 2] = pool_gb[g][:, None]
-                    pl_t = np.full(n_vms, -1, np.int32)
-                    mg_t = np.zeros(n_vms, bool)
-                    rej0 = 0
-                else:
-                    i = ev // SNAP
-                    base[:, :n_srv, 0] = traj.snap_cores[i]
-                    base[:, :n_srv, 1] = \
-                        server_gb[g][:, None] - traj.snap_mem[i]
-                    base[:, :n_srv, 2] = \
-                        pool_gb[g][:, None] - traj.snap_pool[i][self.group_of]
-                    pl_t = np.where((traj.arr_idx < ev)
-                                    & (traj.dep_idx >= ev)
-                                    & (traj.srv >= 0), traj.srv,
-                                    -1).astype(np.int32)
-                    mg_t = (pl_t >= 0) & traj.mig & (traj.mig_idx < ev)
-                    rej0 = int(traj.snap_rejects[i])
-                base[:, n_srv, :] = -_INF
-                # the fast departure path assumes uniform placement state
-                clean -= {v for v in clean if pl_t[v] < 0 or mg_t[v]}
-                free = np.concatenate([free, base])
-                placed = np.concatenate([placed, np.tile(pl_t, (k, 1))])
-                migrated = np.concatenate([migrated, np.tile(mg_t, (k, 1))])
-                rejects = np.concatenate(
-                    [rejects, np.full(k, rej0, np.int64)])
-                alive = np.concatenate([alive, g])
-                cidx = np.arange(len(alive))
+        for e in range(n_ev):
             cand_events += len(alive)
             v = ev_vm[e]
             kind = ev_kind[e]
             if kind > MIGRATE:      # FAIL/RECOVER: happy-path no-ops
-                e += 1              # (availability() prices them)
-                continue
+                continue            # (availability() prices them)
             if kind == DEPART:
                 if v in clean:                   # all rows placed, none
                     s = placed[:, v]             # migrated
@@ -998,7 +754,6 @@ class CompiledReplay:
                         free[cidx[:, None], gcols[s], 2] += p
                     placed[:, v] = -1
                     clean.discard(v)
-                    e += 1
                     continue
                 s = placed[:, v]
                 rows = cidx[s >= 0]
@@ -1012,7 +767,6 @@ class CompiledReplay:
                         np.where(mg, 0.0, pool_of[v])[:, None]
                     migrated[rows, v] = False
                 placed[:, v] = -1
-                e += 1
                 continue
             if kind == MIGRATE:
                 # QoS mitigation: copy the pooled GBs back to local if the
@@ -1029,7 +783,6 @@ class CompiledReplay:
                         free[rows[:, None], gcols[sv], 2] += p
                         migrated[rows, v] = True
                         clean.discard(v)
-                e += 1
                 continue
             # ---- ARRIVE: best fit by cores among servers whose free local
             # memory fits; pool checked per group (same mask as the oracle,
@@ -1046,7 +799,6 @@ class CompiledReplay:
                     free[cidx[:, None], gcols[s], 2] -= p
                 placed[:, v] = s
                 clean.add(v)
-                e += 1
                 continue
             infeas = np.isinf(best)
             rows = cidx[~infeas]
@@ -1086,24 +838,17 @@ class CompiledReplay:
                         rejects = rejects[keep]
                         cidx = np.arange(len(alive))
                         if not len(alive):
-                            if wi < len(entries):  # skip to next wave
-                                e = entries[wi][0]
-                                continue
                             break
-            e += 1
 
         rates[alive] = rejects / denom
-        _STATS.sweeps += 1
-        _STATS.events += n_ev
-        _STATS.candidate_events += cand_events
-        _STATS.wall_s += time.perf_counter() - t0
+        _count_sweep(t0, n_ev, cand_events)
         return rates
 
     # ------------------------------------------------------------- fleet --
     def _fleet_events_np(self):
         """Slot-mapped numpy event arrays for the fleet sweep (cached):
         one shard dict shaped like a streaming shard, spanning the whole
-        trace, float payloads (the numpy fleet backend carries float64
+        trace, float payloads (the numpy fleet sweep carries float64
         state, so non-integral decisions replay exactly too)."""
         if getattr(self, "_fleet_ev_np", None) is None:
             ev_slot, next_slot = sweep_core.assign_slots(
@@ -1122,7 +867,6 @@ class CompiledReplay:
 
     @obs.traced("replay.fleet")
     def reject_rates_fleet(self, server_gb, pod_gb, topology,
-                           backend: str = "auto",
                            state_dtype: str | None = None) -> np.ndarray:
         """Reject fraction per ``(server_gb, pod capacities, topology)``
         fleet candidate — the multi-pod analog of :meth:`reject_rates`.
@@ -1132,77 +876,41 @@ class CompiledReplay:
         sequence of per-lane topologies (all at this engine's
         ``n_servers``); ``pod_gb`` broadcasts per
         :func:`_fleet_candidates` (scalar, shared per-pod array, or
-        per-lane entries).  One event scan prices the whole grid; both
-        backends are bit-exact against the scalar oracle
-        ``cluster_sim.replay_multi_pool`` (the jax path on integral-GB
-        traces, the numpy path unconditionally).
+        per-lane entries).  One event scan prices the whole grid,
+        bit-exact against the scalar oracle
+        ``cluster_sim.replay_multi_pool``: the XLA pod sweep (row 0 of a
+        K=1 :class:`CompiledReplayBatch`) on integral-GB traces, the
+        float64 numpy sweep otherwise.
 
         Usage (price a topology frontier at equal hardware)::
 
             caps = [topology.split_pool(960.0, t.n_pods) for t in topos]
             rates = eng.reject_rates_fleet(320.0, caps, topos)
         """
-        t0 = time.perf_counter()
         sgb, caps, topos = _fleet_candidates(server_gb, pod_gb, topology)
         if topos[0].n_servers != self.n_servers:
             raise ValueError(
                 f"topology covers {topos[0].n_servers} servers; engine "
                 f"has {self.n_servers}")
-        n0 = len(sgb)
-        denom = max(self.n_vms, 1)
         if not self.n_events:
-            return np.zeros(n0)
-        if _auto_backend(backend, self._exact) == "jax":
-            rates = self._fleet_rates_jax(sgb, caps, topos, state_dtype)
-        else:
-            ev = self._fleet_events_np()
-            state = _np_fleet_state(n0, self.n_servers,
-                                    self.cores_per_server, sgb, caps,
-                                    ev["n_slots"])
-            inc, _ = _fleet_incidence(topos, self.n_servers,
-                                      self.n_servers)
-            _np_fleet_sweep(ev, inc, *state)
-            rates = state[-1] / denom
-        _STATS.sweeps += 1
-        _STATS.events += self.n_events
-        _STATS.candidate_events += self.n_events * n0
-        _STATS.wall_s += time.perf_counter() - t0
-        return rates
+            return np.zeros(len(sgb))
+        if not _on_device(self._exact):
+            return self._fleet_rates_numpy(sgb, caps, topos)
+        return self._as_batch()._fleet_rates(sgb, caps, topos,
+                                             state_dtype)[0]
 
-    def _fleet_rates_jax(self, sgb, caps, topos,
-                         state_dtype: str | None = None) -> np.ndarray:
-        """XLA pod sweep over the fleet grid, in candidate chunks."""
-        evs, _group_of, n_slots, s_pad, _g_pad = self._jax_events()
-        n0 = len(sgb)
-        rejects = np.empty(n0, np.int64)
-        inc, p_max = _fleet_incidence(topos, self.n_servers, s_pad)
-        sgb_i, _ = sweep_core.quantize_capacities(sgb, np.zeros(n0))
-        caps_i = np.clip(np.floor(caps), -sweep_core.I32_BIG,
-                         sweep_core.I32_BIG)
-        dt_name = state_dtype or sweep_core.pick_pod_state_dtype(
-            self.cores_per_server, self.n_servers, sgb_i, caps_i,
-            self._pay_mem_max, self._pay_pool_max, self._mig_pool_sum,
-            p_max)
-        np_dt = sweep_core.state_np_dtype(dt_name)
-        p_pad = sweep_core.pad_up(p_max, sweep_core.LANE_PAD)
-        pgb_i = np.zeros((n0, p_pad))
-        pgb_i[:, :caps_i.shape[1]] = caps_i
-        sweep = sweep_core.get_pod_sweep(dt_name)
-        rec = obs.get_recorder()
-        for lo, hi, width in sweep_core.candidate_chunks(n0):
-            sgb_w, pgb_w, inc_w = sweep_core.pod_lane_arrays(
-                sgb_i, pgb_i, inc, lo, hi, width, np_dt)
-            fc0, um0, up0, slots0, pods0, _ = sweep_core.init_pod_state(
-                width, self.n_servers, self.cores_per_server, s_pad,
-                p_pad, n_slots, np_dt)
-            args = tuple(sweep_core.device_put(a) for a in
-                         (inc_w, fc0, um0, up0, slots0, pods0, sgb_w,
-                          pgb_w))
-            with rec.span("replay.compute"):
-                out = sweep(evs, *args)
-                rejects[lo:hi] = np.asarray(out)[:hi - lo]
-            rec.count("sweep.steps", self.n_events)
-        return rejects / max(self.n_vms, 1)
+    def _fleet_rates_numpy(self, sgb, caps, topos) -> np.ndarray:
+        """The float64 fleet sweep over normalized lanes (see
+        :func:`_np_fleet_sweep`)."""
+        t0 = time.perf_counter()
+        ev = self._fleet_events_np()
+        state = _np_fleet_state(len(sgb), self.n_servers,
+                                self.cores_per_server, sgb, caps,
+                                ev["n_slots"])
+        inc, _ = _fleet_incidence(topos, self.n_servers, self.n_servers)
+        _np_fleet_sweep(ev, inc, *state)
+        _count_sweep(t0, self.n_events, self.n_events * len(sgb))
+        return state[-1] / max(self.n_vms, 1)
 
 
 # ----------------------------------------------------------- fleet sweeps --
@@ -1401,8 +1109,8 @@ def _np_fleet_state(n_cand: int, n_servers: int, cores_per_server,
 def _np_stream_sweep(shard, gcols, free, placed, migrated, rejects):
     """Numpy shard sweep over carried state (float64, oracle-ordered ops).
 
-    Vectorized over candidates like the divergence-window backend's wave
-    loop, but slot-indexed and carry-threaded: ``free`` is the packed
+    Vectorized over candidates like ``CompiledReplay``'s numpy sweep,
+    but slot-indexed and carry-threaded: ``free`` is the packed
     ``(C, n_servers + 1, 3)`` free-capacity array (cores / local GB /
     mirrored group pool GB; the +1 dummy column absorbs ragged pool
     groups), ``placed``/``migrated`` are ``(C, n_slots)`` placement
@@ -1507,14 +1215,14 @@ class CheckpointSpec:
     to ``path`` (one ``.npz``, written atomically: tmp file +
     ``os.replace``, so a kill mid-write never corrupts the previous
     snapshot).  With ``resume=True`` an existing checkpoint whose
-    fingerprint matches the sweep (backend, state dtype, event/shard
+    fingerprint matches the sweep (sweep path, state dtype, event/shard
     counts, candidate grid bytes, reject cap) is loaded first and the
     sweep fast-forwards — completed candidate chunks keep their
     counts, the current chunk restarts from the checkpointed shard
     with the restored carry.  Resumed results are BIT-IDENTICAL to an
     uninterrupted sweep (``tests/test_checkpoint_stream.py`` kills at
     shard k
-    and proves it, both backends, both state dtypes); a fingerprint
+    and proves it, both sweep paths, both state dtypes); a fingerprint
     mismatch raises ``ValueError`` rather than silently pricing a
     different sweep.
 
@@ -1563,7 +1271,7 @@ class _CheckpointIO:
         if got != self.fp:
             raise ValueError(
                 f"checkpoint {self.spec.path} belongs to a different "
-                "sweep (backend/state dtype/trace/candidates/reject cap "
+                "sweep (sweep path/state dtype/trace/candidates/reject cap "
                 "changed); delete it or rerun the original sweep")
         return state
 
@@ -1640,6 +1348,19 @@ def _count_out_devices(rec, out) -> None:
     shows that ``devices=`` really partitioned the sweep."""
     if rec.enabled:
         rec.count(f"sweep.out_devices.{len(out.devices())}")
+
+
+def _lane_shardings(mesh):
+    """Placements of one lane-split chunk of the batched carry sweep,
+    as ``(carry, capacities, events)``: the candidate-lane axis of the
+    carry (dim 1; dim 2 of the slot array) and of the capacities split
+    over ``mesh``, the events replicated.  All ``None`` without one."""
+    if mesh is None:
+        return (None,) * 5, None, None
+    lane = sweep_core.named_sharding(mesh, None, "shard")
+    slots = sweep_core.named_sharding(mesh, None, None, "shard")
+    return ((lane, lane, lane, slots, lane), lane,
+            sweep_core.named_sharding(mesh))
 
 
 def _count_scanned(rec, lanes: int, arrivals: int, events: int,
@@ -1907,6 +1628,7 @@ class CompiledReplayStream:
         self._pay_pool_max = 0.0
         self._has_migrate = False
         self._mig_pool_sum = 0.0      # compiled MIGRATE-event pool total
+        self._batch = None
 
         it = iter(vms)
         first = next(it, None)
@@ -2083,8 +1805,7 @@ class CompiledReplayStream:
                     s[key] = np.concatenate([s[key], np.zeros(pad)])
             if self._exact:
                 # integral payloads: store int32 once so sweeps upload
-                # without a per-call astype (the numpy backend computes
-                # the same float64 results from them)
+                # without a per-call astype
                 for key in ("c", "l", "p", "m"):
                     s[key] = s[key].astype(np.int32)
         group_np = np.zeros(self._s_pad, np.int32)
@@ -2103,10 +1824,17 @@ class CompiledReplayStream:
     # class mirrors attribute-for-attribute)
     _pick_state_dtype = CompiledReplay._pick_state_dtype
 
+    def _as_batch(self) -> "CompiledReplayStreamBatch":
+        """This stream as the one row of a
+        :class:`CompiledReplayStreamBatch`, built on first use: the
+        batch's drivers run every XLA sweep of a single stream."""
+        if self._batch is None:
+            self._batch = CompiledReplayStreamBatch([self])
+        return self._batch
+
     @obs.traced("stream.reject_rates")
     def reject_rates(self, server_gb, pool_gb,
                      reject_cap: int | None = None,
-                     backend: str = "auto",
                      state_dtype: str | None = None,
                      checkpoint: "CheckpointSpec | None" = None,
                      devices=None,
@@ -2124,29 +1852,19 @@ class CompiledReplayStream:
         candidate exceeds the cap (each reported rate is then its exact
         count so far — a lower bound at or above
         ``(reject_cap + 1) / n_vms``, satisfying the same
-        feasibility-test contract as the other backends).
+        feasibility-test contract as the other engines).
 
-        The XLA backend pipelines host shard packing + ``device_put``
-        of shard i+1 with shard i's scan (obs spans ``stream.upload`` /
-        ``stream.compute``; ``stream.overlap_ratio`` in
-        ``obs.metrics()`` measures the overlap).  ``devices`` shards
-        the candidate-lane axis across JAX devices via
-        ``shard_map`` — ``"all"``, an int, or an explicit device list
-        (see :func:`sweep_core.resolve_devices`) — bit-exact vs
-        single-device.  ``skip_windows`` (default on) skips leading
-        event shards inside each candidate chunk's divergence window:
-        shards where no lane's capacity can bind start from a
-        precomputed boundary snapshot instead of scanning, bit-exact vs
-        the unskipped sweep (without ``reject_cap``; with a cap both
-        paths satisfy the same lower-bound contract but may stop at
-        different shards).
-
-        ``checkpoint`` (a :class:`CheckpointSpec`) snapshots the packed
-        carry + cursors to disk every N shard sweeps and, with
-        ``resume=True``, fast-forwards an interrupted sweep — resumed
-        results are bit-identical to an uninterrupted run, both
-        backends.  Under ``POND_DEBUG_INVARIANTS=1`` the carry is
-        verified after every shard (``sweep_core.check_invariants``).
+        On integral-GB input the stream prices as row 0 of a K=1
+        :class:`CompiledReplayStreamBatch`, which takes ``state_dtype``,
+        ``checkpoint``, ``devices`` (here: the candidate-lane axis split
+        across JAX devices, bit-exact vs single-device) and
+        ``skip_windows`` as they are; see
+        :meth:`CompiledReplayStreamBatch.reject_rates`.  Non-integral
+        input runs a float64 numpy shard sweep over the same carry,
+        which honours ``reject_cap`` and ``checkpoint`` (resumed
+        results are bit-identical on both paths).  Under
+        ``POND_DEBUG_INVARIANTS=1`` the carry is verified after every
+        shard (``sweep_core.check_invariants``).
 
         Usage::
 
@@ -2155,33 +1873,25 @@ class CompiledReplayStream:
             rates = stream.reject_rates(
                 np.linspace(200., 400., 9), np.linspace(0., 800., 9))
         """
-        t0 = time.perf_counter()
         server_gb = np.atleast_1d(np.asarray(server_gb, float))
         pool_gb = np.atleast_1d(np.asarray(pool_gb, float))
         server_gb, pool_gb = np.broadcast_arrays(server_gb, pool_gb)
-        n0 = len(server_gb)
-        denom = max(self.n_vms, 1)
         if not self.n_events:
-            return np.zeros(n0)
-        if _auto_backend(backend, self._exact) == "jax":
-            rejects, cand_events = self._sweep_jax(
-                server_gb, pool_gb, reject_cap, state_dtype, checkpoint,
-                devices=devices, skip_windows=skip_windows)
-        else:
-            rejects, cand_events = self._sweep_numpy(
-                server_gb, pool_gb, reject_cap, checkpoint)
-        _STATS.sweeps += 1
-        _STATS.events += self.n_events
-        _STATS.candidate_events += cand_events
-        _STATS.wall_s += time.perf_counter() - t0
-        return rejects / denom
+            return np.zeros(len(server_gb))
+        if not _on_device(self._exact):
+            return self._sweep_numpy(server_gb, pool_gb, reject_cap,
+                                     checkpoint)
+        return self._as_batch()._reject_rates(
+            server_gb[None], pool_gb[None], reject_cap, state_dtype,
+            checkpoint, devices, skip_windows)[0]
 
-    def _checkpoint_io(self, backend, dt_name, reject_cap, server_gb,
-                       pool_gb, spec):
+    def _checkpoint_io(self, reject_cap, server_gb, pool_gb, spec):
+        """The checkpoint of the numpy shard sweep, the one a single
+        stream runs itself (its XLA sweeps checkpoint in the batch)."""
         if spec is None:
             return None, None
         io = _CheckpointIO(spec, _sweep_fingerprint(
-            backend, dt_name, self.n_events, self.n_shards, self.n_vms,
+            "numpy", "float64", self.n_events, self.n_shards, self.n_vms,
             reject_cap, server_gb, pool_gb))
         return io, io.load()
 
@@ -2203,149 +1913,8 @@ class CompiledReplayStream:
         return [int(np.count_nonzero(s["kind"] == ARRIVE))
                 for s in self._shards]
 
-    def _shard_host(self, si: int):
-        """Builder for one shard's six int32 event columns — runs on
-        the upload worker so host packing overlaps device compute."""
-        shard = self._shards[si]
-
-        def build():
-            return tuple(
-                a if a.dtype == np.int32 else a.astype(np.int32)
-                for a in (shard["kind"], shard["slot"], shard["c"],
-                          shard["l"], shard["p"], shard["m"]))
-
-        return build
-
-    def _sweep_jax(self, server_gb, pool_gb, reject_cap, state_dtype,
-                   ckpt=None, devices=None, skip_windows=True):
-        rec = obs.get_recorder()
-        n0 = len(server_gb)
-        rejects = np.empty(n0, np.int64)
-        sgb_i, pgb_i = sweep_core.quantize_capacities(server_gb, pool_gb)
-        dt_name = state_dtype or self._pick_state_dtype(sgb_i, pgb_i)
-        np_dt = sweep_core.state_np_dtype(dt_name)
-        devs = sweep_core.resolve_devices(devices)
-        ref = _stream_reference(self) if skip_windows else None
-        cand_events = 0
-        io, st = self._checkpoint_io("jax", dt_name, reject_cap,
-                                     server_gb, pool_gb, ckpt)
-        start_chunk = start_shard = 0
-        resumed = None
-        if st is not None:
-            start_chunk, start_shard = (int(st["chunk_idx"]),
-                                        int(st["shard_idx"]))
-            n_done = int(st["n_done"])
-            rejects[:n_done] = st["rejects_done"]
-            resumed = tuple(st[f"carry{j}"] for j in range(5))
-            io.shards_done = int(st["shards_done"])
-        debug = sweep_core.invariants_enabled()
-        if debug:
-            self._debug_check_events()
-        pool = _upload_pool()
-        for ci, (lo, hi, width) in enumerate(
-                sweep_core.candidate_chunks(n0)):
-            if ci < start_chunk:
-                continue              # counts restored from checkpoint
-            k = hi - lo
-            # candidate-lane sharding: split the lane axis over as many
-            # devices as divide this chunk's bucket width
-            mesh = sh_lane = sh_slot = sh_rep = None
-            if devs is not None:
-                n_lane = sweep_core.lane_shard_count(width, len(devs))
-                if n_lane >= 2:
-                    mesh = sweep_core.shard_mesh(devs[:n_lane])
-                    sh_lane = sweep_core.named_sharding(mesh, "shard")
-                    sh_slot = sweep_core.named_sharding(mesh, None,
-                                                        "shard")
-                    sh_rep = sweep_core.named_sharding(mesh)
-            # the carry variant donates the packed state back to the
-            # sweep: shard-to-shard state stays device-resident
-            sweep = sweep_core.get_sweep(dt_name, with_carry=True,
-                                         mesh=mesh, shard_axis="lane")
-            group_j = sweep_core.device_put(self._group_np, sh_rep)
-            sgb, pgb = sweep_core.lane_capacities(sgb_i, pgb_i, lo, hi,
-                                                  width, np_dt)
-            if resumed is not None:
-                carry0 = resumed
-                shard_from, resumed = start_shard, None
-            elif ref is not None:
-                # divergence window: every lane in the chunk provably
-                # replays the reference through these leading shards —
-                # start from the boundary snapshot instead of scanning
-                shard_from = _skip_count(ref, sgb_i[lo:hi].min(),
-                                         pgb_i[lo:hi].min(),
-                                         self.n_shards)
-                carry0 = _carry_from_snap(
-                    ref["snaps"][shard_from], width, self.n_servers,
-                    self.n_groups, self._s_pad, self._g_pad,
-                    self._n_slots, np_dt, dt_name)
-                if shard_from and rec.enabled:
-                    rec.count("stream.shards_skipped", shard_from)
-                    rec.count("stream.events_skipped",
-                              shard_from * self.shard_pad_events * width)
-            else:
-                carry0 = sweep_core.init_state(
-                    width, self.n_servers, self.cores_per_server,
-                    self._s_pad, self._g_pad, self._n_slots, np_dt)
-                shard_from = 0
-            carry = tuple(sweep_core.device_put(a, s) for a, s in zip(
-                carry0, (sh_lane, sh_lane, sh_lane, sh_slot, sh_lane)))
-            sgb_j = sweep_core.device_put(sgb, sh_lane)
-            pgb_j = sweep_core.device_put(pgb, sh_lane)
-            # double buffering: shard i+1 packs + uploads on a worker
-            # thread while shard i's scan runs; at most TWO shard
-            # tensors are ever in flight (2 * peak_shard_bytes)
-            fut = None
-            if shard_from < self.n_shards:
-                fut = pool.submit(_upload_job, self._shard_host(shard_from),
-                                  sh_rep)
-            end = self.n_shards
-            for si in range(shard_from, self.n_shards):
-                with rec.span("stream.shard", shard=si, chunk=ci):
-                    with rec.span("stream.upload_wait", shard=si):
-                        evs, up0, up1, nbytes = fut.result()
-                    if rec.enabled:
-                        rec.add_span("stream.upload", up0, up1, shard=si)
-                        rec.count("device_put.calls", 6)
-                        rec.count("device_put.bytes", nbytes)
-                    if si + 1 < self.n_shards:
-                        fut = pool.submit(_upload_job,
-                                          self._shard_host(si + 1),
-                                          sh_rep)
-                    with rec.span("stream.compute", shard=si):
-                        carry = sweep(evs, group_j, *carry, sgb_j, pgb_j)
-                        if rec.enabled:
-                            carry[0].block_until_ready()
-                    rec.count("sweep.steps", self.shard_events[si])
-                    if rec.enabled:
-                        _count_scanned(rec, k, self.shard_arrivals[si],
-                                       self.shard_events[si],
-                                       self.n_servers, self.n_groups, 2)
-                cand_events += self.shard_pad_events * width
-                if debug:
-                    self._debug_check_carry(carry[0], carry[1],
-                                            carry[2], si)
-                if io is not None:
-                    io.tick(lambda: {
-                        "chunk_idx": ci, "shard_idx": si + 1,
-                        "n_done": lo, "rejects_done": rejects[:lo],
-                        "shards_done": io.shards_done,
-                        **{f"carry{j}": np.asarray(c)
-                           for j, c in enumerate(carry)}})
-                if reject_cap is not None:
-                    rej_now = np.asarray(carry[4])[:k]
-                    if (rej_now > reject_cap).all():
-                        rec.count("stream.reject_cap_exits")
-                        end = si + 1
-                        break                   # every candidate decided
-            _count_unscanned(rec, self.shard_events, shard_from, end)
-            rejects[lo:hi] = np.asarray(carry[4])[:k]
-            _count_out_devices(rec, carry[4])
-        if io is not None:
-            io.done()
-        return rejects, cand_events
-
     def _sweep_numpy(self, server_gb, pool_gb, reject_cap, ckpt=None):
+        t0 = time.perf_counter()
         n0 = len(server_gb)
         n_srv = self.n_servers
         free = np.empty((n0, n_srv + 1, 3))
@@ -2357,8 +1926,8 @@ class CompiledReplayStream:
         migrated = np.zeros((n0, self._n_slots), bool)
         rejects = np.zeros(n0, np.int64)
         cand_events = 0
-        io, st = self._checkpoint_io("numpy", "float64", reject_cap,
-                                     server_gb, pool_gb, ckpt)
+        io, st = self._checkpoint_io(reject_cap, server_gb, pool_gb,
+                                     ckpt)
         start_shard = 0
         if st is not None:
             free, placed, migrated = (st["free"], st["placed"],
@@ -2394,13 +1963,13 @@ class CompiledReplayStream:
                 break
         if io is not None:
             io.done()
-        return rejects, cand_events
+        _count_sweep(t0, self.n_events, cand_events)
+        return rejects / max(self.n_vms, 1)
 
     # ------------------------------------------------------------- fleet --
     @obs.traced("stream.fleet")
     def reject_rates_fleet(self, server_gb, pod_gb, topology,
                            reject_cap: int | None = None,
-                           backend: str = "auto",
                            state_dtype: str | None = None) -> np.ndarray:
         """Fleet reject rates, streamed shard by shard.
 
@@ -2408,94 +1977,27 @@ class CompiledReplayStream:
         :meth:`CompiledReplay.reject_rates_fleet`; the pod carry (now
         including the per-pod used-pool matrix and the granting-pod
         slot array) threads between shards exactly like the single-pool
-        streaming sweep, device-resident on the jax backend.  With
-        ``reject_cap`` set the stream stops early once EVERY lane
-        exceeds the cap (exact counts so far — the usual
-        feasibility-test lower-bound contract).
+        streaming sweep — device-resident, as row 0 of a K=1
+        :class:`CompiledReplayStreamBatch`, on integral-GB input; a
+        float64 numpy shard sweep otherwise.  With ``reject_cap`` set
+        the stream stops early once EVERY lane exceeds the cap (exact
+        counts so far — the usual feasibility-test lower-bound
+        contract).
         """
-        t0 = time.perf_counter()
         sgb, caps, topos = _fleet_candidates(server_gb, pod_gb, topology)
         if topos[0].n_servers != self.n_servers:
             raise ValueError(
                 f"topology covers {topos[0].n_servers} servers; stream "
                 f"has {self.n_servers}")
-        n0 = len(sgb)
-        denom = max(self.n_vms, 1)
         if not self.n_events:
-            return np.zeros(n0)
-        if _auto_backend(backend, self._exact) == "jax":
-            rejects, cand_events = self._fleet_sweep_jax(
-                sgb, caps, topos, reject_cap, state_dtype)
-        else:
-            rejects, cand_events = self._fleet_sweep_numpy(
-                sgb, caps, topos, reject_cap)
-        _STATS.sweeps += 1
-        _STATS.events += self.n_events
-        _STATS.candidate_events += cand_events
-        _STATS.wall_s += time.perf_counter() - t0
-        return rejects / denom
-
-    def _fleet_sweep_jax(self, sgb, caps, topos, reject_cap,
-                         state_dtype):
-        rec = obs.get_recorder()
-        n0 = len(sgb)
-        rejects = np.empty(n0, np.int64)
-        inc, p_max = _fleet_incidence(topos, self.n_servers, self._s_pad)
-        sgb_i, _ = sweep_core.quantize_capacities(sgb, np.zeros(n0))
-        caps_i = np.clip(np.floor(caps), -sweep_core.I32_BIG,
-                         sweep_core.I32_BIG)
-        dt_name = state_dtype or sweep_core.pick_pod_state_dtype(
-            self.cores_per_server, self.n_servers, sgb_i, caps_i,
-            self._pay_mem_max, self._pay_pool_max, self._mig_pool_sum,
-            p_max)
-        np_dt = sweep_core.state_np_dtype(dt_name)
-        p_pad = sweep_core.pad_up(p_max, sweep_core.LANE_PAD)
-        pgb_i = np.zeros((n0, p_pad))
-        pgb_i[:, :caps_i.shape[1]] = caps_i
-        sweep = sweep_core.get_pod_sweep(dt_name, with_carry=True)
-        cand_events = 0
-        for lo, hi, width in sweep_core.candidate_chunks(n0):
-            kc = hi - lo
-            sgb_w, pgb_w, inc_w = sweep_core.pod_lane_arrays(
-                sgb_i, pgb_i, inc, lo, hi, width, np_dt)
-            carry = tuple(sweep_core.device_put(a)
-                          for a in sweep_core.init_pod_state(
-                              width, self.n_servers,
-                              self.cores_per_server, self._s_pad,
-                              p_pad, self._n_slots, np_dt))
-            inc_j = sweep_core.device_put(inc_w)
-            sgb_j = sweep_core.device_put(sgb_w)
-            pgb_j = sweep_core.device_put(pgb_w)
-            pool = _upload_pool()
-            fut = pool.submit(_upload_job, self._shard_host(0))
-            end = self.n_shards
-            for si in range(self.n_shards):
-                with rec.span("stream.fleet.shard", shard=si):
-                    with rec.span("stream.upload_wait", shard=si):
-                        evs, up0, up1, nbytes = fut.result()
-                    if rec.enabled:
-                        rec.add_span("stream.upload", up0, up1, shard=si)
-                        rec.count("device_put.calls", 6)
-                        rec.count("device_put.bytes", nbytes)
-                    if si + 1 < self.n_shards:
-                        fut = pool.submit(_upload_job,
-                                          self._shard_host(si + 1))
-                    with rec.span("stream.compute", shard=si):
-                        carry = sweep(evs, inc_j, *carry, sgb_j, pgb_j)
-                        if rec.enabled:
-                            carry[0].block_until_ready()
-                    rec.count("sweep.steps", self.shard_events[si])
-                cand_events += self.shard_pad_events * width
-                if reject_cap is not None:
-                    if (np.asarray(carry[5])[:kc] > reject_cap).all():
-                        rec.count("stream.reject_cap_exits")
-                        end = si + 1
-                        break
-            _count_unscanned(rec, self.shard_events, 0, end)
-            rejects[lo:hi] = np.asarray(carry[5])[:kc]
-        return rejects, cand_events
+            return np.zeros(len(sgb))
+        if not _on_device(self._exact):
+            return self._fleet_sweep_numpy(sgb, caps, topos, reject_cap)
+        return self._as_batch()._fleet_rates(sgb, caps, topos, reject_cap,
+                                             state_dtype)[0]
 
     def _fleet_sweep_numpy(self, sgb, caps, topos, reject_cap):
+        t0 = time.perf_counter()
         n0 = len(sgb)
         inc, _ = _fleet_incidence(topos, self.n_servers, self.n_servers)
         state = _np_fleet_state(n0, self.n_servers, self.cores_per_server,
@@ -2511,7 +2013,8 @@ class CompiledReplayStream:
             if reject_cap is not None and (state[-1] > reject_cap).all():
                 rec.count("stream.reject_cap_exits")
                 break
-        return state[-1], cand_events
+        _count_sweep(t0, self.n_events, cand_events)
+        return state[-1] / max(self.n_vms, 1)
 
 
 # ----------------------------------------------------------- trace batch ---
@@ -2611,11 +2114,9 @@ class CompiledReplayBatch:
         for j, fill in enumerate(fills):
             col = np.full((self.k, e_max), fill, np.int32)
             for i, p in enumerate(per):
-                arr = np.asarray(p[0][j])
-                col[i, :arr.shape[0]] = arr
+                col[i, :p[0][j].shape[0]] = p[0][j]
             cols.append(col)
-        self._jax_host = (cols, np.asarray(per[0][1]), n_slots, s_pad,
-                          g_pad)
+        self._jax_host = (cols, per[0][1], n_slots, s_pad, g_pad)
         return self._jax_host
 
     def _jax_batch_events(self):
@@ -2662,7 +2163,6 @@ class CompiledReplayBatch:
     @obs.traced("batch.reject_rates")
     def reject_rates(self, server_gb, pool_gb,
                      reject_cap: int | None = None,
-                     backend: str = "auto",
                      state_dtype: str | None = None,
                      devices=None) -> np.ndarray:
         """Reject fraction per (trace, candidate): shape ``(K, n_cand)``.
@@ -2671,15 +2171,14 @@ class CompiledReplayBatch:
         (``"all"``, an int, or a device list): the K-trace axis when
         ``K >= n_devices`` (rows pad to a multiple of the mesh size
         with no-op traces), else the candidate-lane axis.  Bit-exact
-        (==) vs single-device; ignored by the numpy fallback.
+        (==) vs single-device; ignored by the numpy sweep.
 
         ``server_gb``/``pool_gb`` broadcast like the single-trace API and
         additionally accept ``(K, n_cand)`` per-trace candidate grids.
-        ``backend="auto"`` prices all K traces in ONE vmapped integer
-        ``lax.scan`` when jax is importable and every trace's decisions
-        are integral GBs; otherwise it falls back to looping the
-        per-trace numpy divergence-window sweep (same bit-exact rates,
-        just K sweeps instead of one).
+        When every trace's decisions are integral GBs, all K traces
+        price in ONE vmapped integer ``lax.scan``; otherwise each trace
+        runs its float64 numpy sweep (same bit-exact rates, just K
+        sweeps instead of one).
 
         The batched carry packs to int16 when every trace's capacities
         permit (the keyed ``sweep_core`` cache compiles one vmapped
@@ -2688,18 +2187,23 @@ class CompiledReplayBatch:
         ``reject_cap`` is accepted for engine interchangeability with
         the streaming batch: the monolithic vmapped sweep always
         returns exact rates (which satisfy the same feasibility-test
-        contract), while the numpy fallback forwards the cap to the
-        per-trace sweeps.
+        contract), while the numpy sweeps take the cap.
         """
         server_gb, pool_gb = _broadcast_candidates(self.k, server_gb,
                                                    pool_gb)
-        n0 = server_gb.shape[1]
-        backend = _auto_backend(backend, self._exact)
-        if backend != "jax":
+        if not _on_device(self._exact):
             return np.stack([
-                eng.reject_rates(server_gb[i], pool_gb[i],
-                                 reject_cap=reject_cap, backend=backend)
+                eng._reject_rates_numpy(server_gb[i], pool_gb[i],
+                                        reject_cap)
                 for i, eng in enumerate(self.engines)])
+        return self._reject_rates(server_gb, pool_gb, state_dtype,
+                                  devices)
+
+    def _reject_rates(self, server_gb, pool_gb, state_dtype,
+                      devices) -> np.ndarray:
+        """The plain XLA driver over ``(K, n_cand)`` candidates: one
+        vmapped sweep per candidate chunk."""
+        n0 = server_gb.shape[1]
         t0 = time.perf_counter()
         rejects = np.empty((self.k, n0), np.int64)
         sgb_i, pgb_i = sweep_core.quantize_capacities(server_gb, pool_gb)
@@ -2775,16 +2279,12 @@ class CompiledReplayBatch:
                                int(self.n_events.sum()), self.n_servers,
                                self.engines[0].n_groups, 1 + self.k)
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
-        _STATS.sweeps += 1
-        _STATS.events += steps
-        _STATS.candidate_events += int(self.n_events.sum()) * n0
-        _STATS.wall_s += time.perf_counter() - t0
+        _count_sweep(t0, steps, int(self.n_events.sum()) * n0)
         return rates
 
     # ------------------------------------------------------------- fleet --
     @obs.traced("batch.fleet")
     def reject_rates_fleet(self, server_gb, pod_gb, topology,
-                           backend: str = "auto",
                            state_dtype: str | None = None,
                            devices=None) -> np.ndarray:
         """Fleet reject rates per (trace, candidate): ``(K, n_cand)``.
@@ -2797,21 +2297,23 @@ class CompiledReplayBatch:
         ``devices`` shards the K-trace axis over a device mesh (rows
         pad with no-op traces), bit-exact vs single-device.
         """
-        t0 = time.perf_counter()
         sgb, caps, topos = _fleet_candidates(server_gb, pod_gb, topology)
         if topos[0].n_servers != self.n_servers:
             raise ValueError(
                 f"topology covers {topos[0].n_servers} servers; batch "
                 f"has {self.n_servers}")
+        if not _on_device(self._exact):
+            return np.stack([eng._fleet_rates_numpy(sgb, caps, topos)
+                             for eng in self.engines])
+        return self._fleet_rates(sgb, caps, topos, state_dtype, devices)
+
+    def _fleet_rates(self, sgb, caps, topos, state_dtype,
+                     devices=None) -> np.ndarray:
+        """The fleet XLA driver over normalized lanes (see
+        :func:`_fleet_candidates`): one vmapped pod sweep per candidate
+        chunk."""
+        t0 = time.perf_counter()
         n0 = len(sgb)
-        backend = _auto_backend(backend, self._exact)
-        if backend != "jax":
-            # trim the dense capacity rows back to each lane's pod count
-            per_lane = [caps[i, :t.n_pods] for i, t in enumerate(topos)]
-            return np.stack([
-                eng.reject_rates_fleet(sgb, per_lane, topos,
-                                       backend=backend)
-                for eng in self.engines])
         devs = sweep_core.resolve_devices(devices)
         mesh = sh_row = sh_rep = None
         k_pad = self.k
@@ -2869,10 +2371,7 @@ class CompiledReplayBatch:
                 rejects[:, lo:hi] = np.asarray(out)[:self.k, :kc]
             rec.count("sweep.steps", steps)
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
-        _STATS.sweeps += 1
-        _STATS.events += steps
-        _STATS.candidate_events += int(self.n_events.sum()) * n0
-        _STATS.wall_s += time.perf_counter() - t0
+        _count_sweep(t0, steps, int(self.n_events.sum()) * n0)
         return rates
 
     def _jax_batch_events_fail(self):
@@ -2891,17 +2390,16 @@ class CompiledReplayBatch:
             for j, fill in enumerate(fills):
                 col = np.full((self.k, e_max), fill, np.int32)
                 for i, p in enumerate(per):
-                    arr = np.asarray(p[0][j])
-                    col[i, :arr.shape[0]] = arr
+                    col[i, :p[0][j].shape[0]] = p[0][j]
                 streams.append(sweep_core.device_put(col))
-        self._jax_batch_fail = (tuple(streams), per[0][1], n_slots,
+        self._jax_batch_fail = (tuple(streams),
+                                sweep_core.device_put(per[0][1]), n_slots,
                                 s_pad, g_pad)
         return self._jax_batch_fail
 
     @obs.traced("batch.availability")
     def availability(self, server_gb, pool_gb,
                      mitigation: str = "remigrate",
-                     backend: str = "auto",
                      state_dtype: str | None = None) -> AvailabilityResult:
         """Failure-priced sweep over all K (trace, schedule) rows at
         once: one vmapped scan per candidate chunk.
@@ -2923,14 +2421,9 @@ class CompiledReplayBatch:
                     "availability sweep needs one per trace")
         server_gb, pool_gb = _broadcast_candidates(self.k, server_gb,
                                                    pool_gb)
-        n0 = server_gb.shape[1]
-        backend = _auto_backend(backend, self._exact, "oracle")
-        t0 = time.perf_counter()
-        if backend != "jax":
-            per = [eng.availability(server_gb[i], pool_gb[i], mitigation,
-                                    backend=backend,
-                                    state_dtype=state_dtype,
-                                    per_failure=False)
+        if not _on_device(self._exact):
+            per = [eng._availability_oracle(server_gb[i], pool_gb[i],
+                                            mitigation, False)
                    for i, eng in enumerate(self.engines)]
             return AvailabilityResult(
                 reject_rate=np.stack([r.reject_rate for r in per]),
@@ -2941,15 +2434,31 @@ class CompiledReplayBatch:
                                           for r in per]),
                 n_failures=np.array([r.n_failures for r in per]),
                 affected_per_failure=None, mitigation=mitigation)
+        return self._availability(server_gb, pool_gb, mitigation,
+                                  state_dtype, per_failure=False)
+
+    def _availability(self, server_gb, pool_gb, mitigation, state_dtype,
+                      per_failure: bool) -> AvailabilityResult:
+        """The failure XLA driver over ``(K, n_cand)`` candidates.  With
+        ``per_failure`` the result holds each trace's ``(n_failures,
+        n_cand)`` VMs-affected-per-failure array, in a list."""
+        t0 = time.perf_counter()
+        n0 = server_gb.shape[1]
         evs, group_of, n_slots, s_pad, g_pad = \
             self._jax_batch_events_fail()
         sgb_i, pgb_i = sweep_core.quantize_capacities(server_gb, pool_gb)
         dt_name = state_dtype or self._pick_state_dtype(sgb_i, pgb_i)
         np_dt = sweep_core.state_np_dtype(dt_name)
         sweep = sweep_core.get_fail_sweep(dt_name, mitigation,
-                                          batched=True, with_dist=False)
+                                          batched=True,
+                                          with_dist=per_failure)
         out = {key: np.empty((self.k, n0), np.int64) for key in
                ("rejects", "affected", "killed", "remig", "lost")}
+        dist = fail_pos = None
+        if per_failure:
+            fail_pos = [np.flatnonzero(np.asarray(e._ev_kind) == FAIL)
+                        for e in self.engines]
+            dist = [np.empty((len(fp), n0), np.int64) for fp in fail_pos]
         rec = obs.get_recorder()
         steps = int(self.n_events.max(initial=0))
         for lo, hi, width in sweep_core.candidate_chunks(n0):
@@ -2971,11 +2480,12 @@ class CompiledReplayBatch:
                 for key, a in zip(("rejects", "affected", "killed",
                                    "remig", "lost"), res[:5]):
                     out[key][:, lo:hi] = np.asarray(a)[:, :kc]
+                if per_failure:
+                    ys = np.asarray(res[5])
+                    for i, fp in enumerate(fail_pos):
+                        dist[i][:, lo:hi] = ys[i][fp, :kc]
             rec.count("sweep.steps", steps)
-        _STATS.sweeps += 1
-        _STATS.events += steps
-        _STATS.candidate_events += int(self.n_events.sum()) * n0
-        _STATS.wall_s += time.perf_counter() - t0
+        _count_sweep(t0, steps, int(self.n_events.sum()) * n0)
         return AvailabilityResult(
             reject_rate=out["rejects"] / np.maximum(self.n_vms,
                                                     1)[:, None],
@@ -2983,7 +2493,7 @@ class CompiledReplayBatch:
             remigrated=out["remig"], lost_vm_minutes=out["lost"],
             n_failures=np.array([e.failure_schedule.n_failures
                                  for e in self.engines]),
-            affected_per_failure=None, mitigation=mitigation)
+            affected_per_failure=dist, mitigation=mitigation)
 
 
 # -------------------------------------------------- streaming trace batch ---
@@ -3013,7 +2523,7 @@ class CompiledReplayStreamBatch:
     :class:`CompiledReplay` — bit-for-bit: padding events are no-ops
     and each (trace, candidate) lane replays independently of its batch
     neighbors (``tests/test_replay_stream.py`` asserts this on the
-    fixture and a 100k-VM trace, both backends and both state dtypes).
+    fixture and a 100k-VM trace, both sweep paths and both state dtypes).
     The carry is placed with ``jax.device_put`` and donated back to the
     sweep, so it stays device-resident across shards (``chip_smoke.py``
     runs this path on a TPU).
@@ -3117,7 +2627,6 @@ class CompiledReplayStreamBatch:
     @obs.traced("stream_batch.reject_rates")
     def reject_rates(self, server_gb, pool_gb,
                      reject_cap: int | None = None,
-                     backend: str = "auto",
                      state_dtype: str | None = None,
                      checkpoint: "CheckpointSpec | None" = None,
                      devices=None,
@@ -3132,13 +2641,14 @@ class CompiledReplayStreamBatch:
         the cap — each reported rate is then its exact count so far, a
         lower bound satisfying the usual feasibility-test contract
         (callers must pass a cap covering every trace's tolerance, i.e.
-        ``max_i floor(tol_i * n_vms_i)``).  ``backend="numpy"`` (or
-        non-integral decisions) loops the per-stream float64 shard
-        sweeps instead — same bit-exact rates, K passes instead of one.
+        ``max_i floor(tol_i * n_vms_i)``).  Non-integral decisions loop
+        the per-stream float64 shard sweeps instead — same bit-exact
+        rates, K passes instead of one.
 
         ``devices`` shards the K-trace axis over a JAX device mesh
-        (rows pad to a mesh-size multiple with no-op traces), bit-exact
-        vs single-device; shard i+1's host stacking + upload always
+        (rows pad to a mesh-size multiple with no-op traces) or, for a
+        single stream, its candidate-lane axis; bit-exact vs
+        single-device either way.  Shard i+1's host stacking + upload always
         pipelines with shard i's scan (obs spans ``stream.upload`` /
         ``stream.compute``), so transient peak event memory is
         ``2 * peak_shard_bytes``.  ``skip_windows`` (default on) skips
@@ -3149,33 +2659,40 @@ class CompiledReplayStreamBatch:
 
         ``checkpoint`` snapshots the batched carry + cursors like the
         single-stream engine (resume is bit-identical and adapts across
-        differing ``devices`` row padding); the numpy fallback derives
+        differing ``devices`` row padding); the numpy sweeps derive
         one per-stream spec per row (``<path>.k<i>``).
         ``POND_DEBUG_INVARIANTS=1`` verifies the per-trace carry after
         every shard.
         """
-        t0 = time.perf_counter()
-        rec = obs.get_recorder()
         server_gb, pool_gb = _broadcast_candidates(self.k, server_gb,
                                                    pool_gb)
-        n0 = server_gb.shape[1]
         if not self.n_shards:
-            return np.zeros((self.k, n0))
-        backend = _auto_backend(backend, self._exact)
-        if backend != "jax":
+            return np.zeros((self.k, server_gb.shape[1]))
+        if not _on_device(self._exact):
             return np.stack([
-                s.reject_rates(server_gb[i], pool_gb[i],
-                               reject_cap=reject_cap, backend=backend,
-                               checkpoint=None if checkpoint is None
+                s._sweep_numpy(server_gb[i], pool_gb[i], reject_cap,
+                               None if checkpoint is None
                                else dataclasses.replace(
                                    checkpoint,
                                    path=f"{checkpoint.path}.k{i}"))
                 for i, s in enumerate(self.engines)])
+        return self._reject_rates(server_gb, pool_gb, reject_cap,
+                                  state_dtype, checkpoint, devices,
+                                  skip_windows)
+
+    def _reject_rates(self, server_gb, pool_gb, reject_cap, state_dtype,
+                      checkpoint, devices, skip_windows) -> np.ndarray:
+        """The plain streaming XLA driver over ``(K, n_cand)``
+        candidates: per candidate chunk, one vmapped carry sweep per
+        shard."""
+        t0 = time.perf_counter()
+        rec = obs.get_recorder()
+        n0 = server_gb.shape[1]
         sgb_i, pgb_i = sweep_core.quantize_capacities(server_gb, pool_gb)
         dt_name = state_dtype or self._pick_state_dtype(sgb_i, pgb_i)
         np_dt = sweep_core.state_np_dtype(dt_name)
         devs = sweep_core.resolve_devices(devices)
-        mesh = sh_row = sh_rep = None
+        mesh = sh_row = sh_rep = lane_devs = None
         k_pad = self.k
         if devs is not None:
             n_use = min(len(devs), self.k)
@@ -3184,10 +2701,14 @@ class CompiledReplayStreamBatch:
                 sh_row = sweep_core.named_sharding(mesh, "shard")
                 sh_rep = sweep_core.named_sharding(mesh)
                 k_pad = -(-self.k // n_use) * n_use
-        sweep = sweep_core.get_sweep(dt_name, with_carry=True,
-                                     batched=True, mesh=mesh,
-                                     shard_axis="trace")
-        group_j = sweep_core.device_put(self._group_np, sh_rep)
+            else:           # one trace: split its candidate lanes instead
+                lane_devs = devs
+        sh_carry, sh_cap, sh_ev = (sh_row,) * 5, sh_row, sh_row
+        if lane_devs is None:
+            sweep = sweep_core.get_sweep(dt_name, with_carry=True,
+                                         batched=True, mesh=mesh,
+                                         shard_axis="trace")
+            group_j = sweep_core.device_put(self._group_np, sh_rep)
         refs = None
         if skip_windows and self._exact:
             refs = [_stream_reference(s) for s in self.engines]
@@ -3219,6 +2740,15 @@ class CompiledReplayStreamBatch:
             if ci < start_chunk:
                 continue
             kc = hi - lo
+            if lane_devs is not None:
+                n_lane = sweep_core.lane_shard_count(width, len(lane_devs))
+                mesh = (sweep_core.shard_mesh(lane_devs[:n_lane])
+                        if n_lane >= 2 else None)
+                sh_carry, sh_cap, sh_ev = _lane_shardings(mesh)
+                sweep = sweep_core.get_sweep(dt_name, with_carry=True,
+                                             batched=True, mesh=mesh,
+                                             shard_axis="lane")
+                group_j = sweep_core.device_put(self._group_np, sh_ev)
             sgb, pgb = sweep_core.lane_capacities(sgb_i, pgb_i, lo, hi,
                                                   width, np_dt)
             if k_pad > self.k:      # no-op rows reuse the last real grid
@@ -3256,15 +2786,15 @@ class CompiledReplayStreamBatch:
                     self._s_pad, self._g_pad, self._n_slots, np_dt,
                     k=k_pad)
                 shard_from = 0
-            carry = tuple(sweep_core.device_put(a, sh_row)
-                          for a in carry0)
-            sgb_j = sweep_core.device_put(sgb, sh_row)
-            pgb_j = sweep_core.device_put(pgb, sh_row)
+            carry = tuple(sweep_core.device_put(a, sh)
+                          for a, sh in zip(carry0, sh_carry))
+            sgb_j = sweep_core.device_put(sgb, sh_cap)
+            pgb_j = sweep_core.device_put(pgb, sh_cap)
             fut = None
             if shard_from < self.n_shards:
                 fut = pool.submit(
                     _upload_job, self._stacked_shard_host(shard_from,
-                                                          k_pad), sh_row)
+                                                          k_pad), sh_ev)
             end = self.n_shards
             for si in range(shard_from, self.n_shards):
                 with rec.span("stream_batch.shard", shard=si, chunk=ci):
@@ -3280,7 +2810,7 @@ class CompiledReplayStreamBatch:
                         fut = pool.submit(
                             _upload_job,
                             self._stacked_shard_host(si + 1, k_pad),
-                            sh_row)
+                            sh_ev)
                     with rec.span("stream.compute", shard=si):
                         carry = sweep(evs, group_j, *carry, sgb_j, pgb_j)
                         if rec.enabled:
@@ -3322,17 +2852,13 @@ class CompiledReplayStreamBatch:
         if io is not None:
             io.done()
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
-        _STATS.sweeps += 1
-        _STATS.events += int(self.n_events.max(initial=0))
-        _STATS.candidate_events += cand_events
-        _STATS.wall_s += time.perf_counter() - t0
+        _count_sweep(t0, int(self.n_events.max(initial=0)), cand_events)
         return rates
 
     # ------------------------------------------------------------- fleet --
     @obs.traced("stream_batch.fleet")
     def reject_rates_fleet(self, server_gb, pod_gb, topology,
                            reject_cap: int | None = None,
-                           backend: str = "auto",
                            state_dtype: str | None = None,
                            devices=None) -> np.ndarray:
         """Fleet reject rates per (trace, candidate): ``(K, n_cand)``,
@@ -3347,23 +2873,26 @@ class CompiledReplayStreamBatch:
         a device mesh (no-op padding rows), bit-exact vs single-device;
         shard uploads double-buffer with the scan like the plain path.
         """
-        t0 = time.perf_counter()
         sgb, caps, topos = _fleet_candidates(server_gb, pod_gb, topology)
         if topos[0].n_servers != self.n_servers:
             raise ValueError(
                 f"topology covers {topos[0].n_servers} servers; batch "
                 f"has {self.n_servers}")
-        n0 = len(sgb)
         if not self.n_shards:
-            return np.zeros((self.k, n0))
-        backend = _auto_backend(backend, self._exact)
-        if backend != "jax":
-            per_lane = [caps[i, :t.n_pods] for i, t in enumerate(topos)]
-            return np.stack([
-                s.reject_rates_fleet(sgb, per_lane, topos,
-                                     reject_cap=reject_cap,
-                                     backend=backend)
-                for s in self.engines])
+            return np.zeros((self.k, len(sgb)))
+        if not _on_device(self._exact):
+            return np.stack([s._fleet_sweep_numpy(sgb, caps, topos,
+                                                  reject_cap)
+                             for s in self.engines])
+        return self._fleet_rates(sgb, caps, topos, reject_cap,
+                                 state_dtype, devices)
+
+    def _fleet_rates(self, sgb, caps, topos, reject_cap, state_dtype,
+                     devices=None) -> np.ndarray:
+        """The streaming fleet XLA driver over normalized lanes: per
+        candidate chunk, one vmapped pod carry sweep per shard."""
+        t0 = time.perf_counter()
+        n0 = len(sgb)
         rec = obs.get_recorder()
         rejects = np.empty((self.k, n0), np.int64)
         inc, p_max = _fleet_incidence(topos, self.n_servers, self._s_pad)
@@ -3446,10 +2975,7 @@ class CompiledReplayStreamBatch:
             _count_unscanned(rec, self.shard_steps, 0, end)
             rejects[:, lo:hi] = np.asarray(carry[5])[:self.k, :kc]
         rates = rejects / np.maximum(self.n_vms, 1)[:, None]
-        _STATS.sweeps += 1
-        _STATS.events += int(self.n_events.max(initial=0))
-        _STATS.candidate_events += cand_events
-        _STATS.wall_s += time.perf_counter() - t0
+        _count_sweep(t0, int(self.n_events.max(initial=0)), cand_events)
         return rates
 
 

@@ -12,6 +12,7 @@ import dataclasses
 import numpy as np
 import pytest
 
+from _fractional import shift_half
 from repro.core import (cluster_sim, replay_engine, sweep_core, topology,
                         traces)
 from repro.runtime.fault import FailureSchedule
@@ -46,6 +47,24 @@ def _assert_same(a, b, ctx):
         assert np.array_equal(getattr(a, f), getattr(b, f)), (ctx, f)
 
 
+def _oracle(vms, dec, cfg, sched, server, pool, mitigation):
+    """The scalar oracle ``cluster_sim.replay_with_failures``, one call
+    per candidate, gathered into an ``AvailabilityResult``."""
+    runs = [cluster_sim.replay_with_failures(vms, dec, cfg, float(s),
+                                             float(p), sched, mitigation)
+            for s, p in zip(server, pool)]
+    return replay_engine.AvailabilityResult(
+        reject_rate=np.array([r.reject_rate for r in runs]),
+        affected=np.array([r.affected for r in runs]),
+        killed=np.array([r.killed for r in runs]),
+        remigrated=np.array([r.remigrated for r in runs]),
+        lost_vm_minutes=np.array([r.lost_vm_minutes for r in runs]),
+        n_failures=runs[0].n_failures,
+        affected_per_failure=np.stack(
+            [np.asarray(r.affected_per_failure) for r in runs], 1),
+        mitigation=mitigation)
+
+
 @pytest.mark.parametrize("mitigation", sweep_core.MITIGATIONS)
 def test_fail_sweep_bit_exact_on_fixture(mitigation):
     vms = traces.load_trace_file(traces.fixture_trace_path())
@@ -60,10 +79,9 @@ def test_fail_sweep_bit_exact_on_fixture(mitigation):
                                        failure_schedule=sched)
     server = np.array([768.0, 120.0, 30.0])
     pool = np.array([512.0, 64.0, 512.0])
-    oracle = eng.availability(server, pool, mitigation, backend="oracle")
+    oracle = _oracle(vms, dec, cfg, sched, server, pool, mitigation)
     for dt in ("int32", "int16"):
-        jx = eng.availability(server, pool, mitigation, backend="jax",
-                              state_dtype=dt)
+        jx = eng.availability(server, pool, mitigation, state_dtype=dt)
         _assert_same(oracle, jx, (mitigation, dt))
         assert np.array_equal(oracle.affected_per_failure,
                               jx.affected_per_failure)
@@ -76,9 +94,8 @@ def test_fail_sweep_bit_exact_seeded(seed, mitigation):
     sched = _schedule(seed)
     eng = replay_engine.CompiledReplay(vms, dec, CFG,
                                        failure_schedule=sched)
-    oracle = eng.availability(_SERVER, _POOL, mitigation,
-                              backend="oracle")
-    jx = eng.availability(_SERVER, _POOL, mitigation, backend="jax")
+    oracle = _oracle(vms, dec, CFG, sched, _SERVER, _POOL, mitigation)
+    jx = eng.availability(_SERVER, _POOL, mitigation)
     _assert_same(oracle, jx, (seed, mitigation))
     assert np.array_equal(oracle.affected_per_failure,
                           jx.affected_per_failure)
@@ -140,6 +157,32 @@ def test_batch_availability_matches_single_rows():
                 assert np.array_equal(getattr(br, f)[i],
                                       getattr(r, f)), (mitigation, i, f)
             assert br.n_failures[i] == r.n_failures
+
+
+@pytest.mark.parametrize("mitigation", sweep_core.MITIGATIONS)
+def test_fractional_availability_prices_on_the_oracle(mitigation):
+    """Non-integral decisions (0.5 GB shifted to the pool) cannot take
+    the int32 failure sweep: the engine loops the scalar oracle, counts
+    the fallback, and single and batched rows agree with it."""
+    from repro.core import obs
+    vms, dec = _trace(3)
+    dec = shift_half(dec)
+    sched = _schedule(3)
+    eng = replay_engine.CompiledReplay(vms, dec, CFG,
+                                       failure_schedule=sched)
+    assert not eng._exact
+    rec = obs.Recorder()
+    with obs.use_recorder(rec):
+        got = eng.availability(_SERVER, _POOL, mitigation)
+        rows = replay_engine.CompiledReplayBatch([eng]).availability(
+            _SERVER, _POOL, mitigation)
+    assert rec.metrics()["replay.backend_numpy"] == 2
+    want = _oracle(vms, dec, CFG, sched, _SERVER, _POOL, mitigation)
+    _assert_same(want, got, mitigation)
+    assert np.array_equal(want.affected_per_failure,
+                          got.affected_per_failure)
+    for f in _FIELDS:
+        assert np.array_equal(getattr(rows, f)[0], getattr(want, f)), f
 
 
 def test_availability_requires_schedule():

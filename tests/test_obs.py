@@ -167,8 +167,8 @@ def test_tracing_identity_of_results():
 @pytest.mark.skipif(not HAS_JAX, reason="needs jax")
 @pytest.mark.parametrize("engine", ["replay", "stream"])
 def test_auto_backend_numpy_fallback_is_counted(engine):
-    """``backend="auto"`` counts every fallback to the numpy sweep
-    (non-integral decisions) and nothing when the XLA sweep runs."""
+    """Every fallback to the numpy sweep (non-integral decisions) is
+    counted, and nothing when the XLA sweep runs."""
     from benchmarks import common
     cfg = cluster_sim.ClusterConfig(n_servers=8, pool_sockets=8)
     vms = common.population().sample_vms(200, 86400.0, seed=4)
@@ -183,13 +183,12 @@ def test_auto_backend_numpy_fallback_is_counted(engine):
         return replay_engine.CompiledReplay(vms, dec, cfg)
 
     counts = []
-    for dec, backend in ((whole, "auto"), (halves, "auto"),
-                         (halves, "numpy")):
+    for dec in (whole, halves):
         rec = obs.Recorder()
         with obs.use_recorder(rec):
-            build(dec).reject_rates([300.0], [64.0], backend=backend)
+            build(dec).reject_rates([300.0], [64.0])
         counts.append(rec.metrics().get("replay.backend_numpy", 0))
-    assert counts == [0, 1, 0]
+    assert counts == [0, 1]
 
 
 # --------------------------------------------------- jit-cache counters ---
@@ -362,8 +361,9 @@ def test_batch_compute_spans_and_steps():
 @pytest.mark.skipif(not HAS_JAX, reason="needs jax")
 @pytest.mark.parametrize("family", ["plain", "fail", "pod"])
 def test_replay_compute_spans_and_steps(family):
-    """Every kernel family of the single-trace engine: one
-    ``replay.compute`` per chunk and its trace's events as steps."""
+    """Every kernel family of the single-trace engine dispatches through
+    its K=1 batch: one ``batch.compute`` per chunk and its trace's
+    events as steps."""
     from repro.core import topology
     from repro.runtime.fault import FailureSchedule
     eng = _small_engine(seed=5)
@@ -382,7 +382,8 @@ def test_replay_compute_spans_and_steps(family):
             eng.reject_rates_fleet(_SERVER, 128.0,
                                    topology.partitioned(8, 4))
     m = rec.metrics()
-    assert m["span.replay.compute.count"] == _n_chunks(len(_SERVER))
+    assert m["span.batch.compute.count"] == _n_chunks(len(_SERVER))
+    assert "span.replay.compute.count" not in m
     assert m["sweep.steps"] == eng.n_events * _n_chunks(len(_SERVER))
 
 

@@ -2,8 +2,9 @@
 
 The engine (core/replay_engine.py) must reproduce
 ``cluster_sim.replay_reject_rate`` EXACTLY — same event order, tie-breaks
-and float semantics — on both its backends (XLA int32 sweep and numpy
-divergence-window sweep), across trace seeds and policies, including
+and float semantics — on both its sweeps (the XLA int32 sweep on
+integral-GB decisions, the float64 numpy sweep on fractional ones),
+across trace seeds and policies, including
 QoS-migration events and the all-local fallback path (tight pools force
 it).  The engine-backed ``savings_analysis`` must agree with the
 scalar-oracle search within search tolerance.
@@ -11,6 +12,7 @@ scalar-oracle search within search tolerance.
 import numpy as np
 import pytest
 
+from _fractional import shift_half
 from repro.core import cluster_sim, replay_engine, traces
 from repro.core.control_plane import ControlPlane, ControlPlaneConfig
 from repro.core.pool_manager import PoolManager
@@ -72,18 +74,24 @@ def test_engine_matches_scalar_oracle_exactly(seed, policy, models):
         for s, p in zip(_SERVER, _POOL)])
     got_auto = eng.reject_rates(_SERVER, _POOL)
     assert got_auto.tolist() == oracle.tolist()
-    got_np = eng.reject_rates(_SERVER, _POOL, backend="numpy")
-    assert got_np.tolist() == oracle.tolist()
+    shifted = shift_half(decisions)
+    eng_np = replay_engine.CompiledReplay(vms, shifted, CFG)
+    assert not eng_np._exact
+    oracle_np = [cluster_sim.replay_reject_rate(vms, shifted, CFG, s, p)
+                 for s, p in zip(_SERVER, _POOL)]
+    got_np = eng_np.reject_rates(_SERVER, _POOL)
+    assert got_np.tolist() == oracle_np
 
 
 def test_reject_cap_preserves_feasibility_classification(models):
     vms, decisions = _world(3, "static", models)
-    eng = replay_engine.CompiledReplay(vms, decisions, CFG)
+    # fractional input: the numpy sweep, which compacts capped lanes
+    eng = replay_engine.CompiledReplay(vms, shift_half(decisions), CFG)
+    assert not eng._exact
     oracle = eng.reject_rates(_SERVER, _POOL)
     tol = float(oracle.min()) + 0.005
     cap = int(np.floor(tol * len(vms)))
-    capped = eng.reject_rates(_SERVER, _POOL, reject_cap=cap,
-                              backend="numpy")
+    capped = eng.reject_rates(_SERVER, _POOL, reject_cap=cap)
     assert ((capped <= tol) == (oracle <= tol)).all()
 
 
@@ -182,11 +190,14 @@ def test_stale_migrate_after_departure_is_dropped(models):
     cfg = cluster_sim.ClusterConfig(n_servers=1, pool_sockets=2,
                                     gb_per_core=4.75)
     eng = replay_engine.CompiledReplay(base, decisions, cfg)
+    shifted = shift_half(decisions)
+    eng_np = replay_engine.CompiledReplay(base, shifted, cfg)
     for s, p in ((16.0, 16.0), (12.0, 4.0), (8.0, 16.0)):
         want = cluster_sim.replay_reject_rate(base, decisions, cfg, s, p)
+        want_np = cluster_sim.replay_reject_rate(base, shifted, cfg, s, p)
         got = eng.reject_rates(s, p)
-        got_np = eng.reject_rates(s, p, backend="numpy")
-        assert got[0] == want and got_np[0] == want, (s, p)
+        got_np = eng_np.reject_rates(s, p)
+        assert got[0] == want and got_np[0] == want_np, (s, p)
 
 
 # ------------------------------------------------------- trace batching ---
@@ -200,16 +211,19 @@ def seed_batch(models):
 
 
 def test_batched_rows_match_single_trace_sweeps_bitwise(seed_batch):
-    _, engines, batch = seed_batch
+    worlds, engines, batch = seed_batch
     got = batch.reject_rates(_SERVER, _POOL)
     want = np.stack([e.reject_rates(_SERVER, _POOL) for e in engines])
     assert got.shape == (len(engines), len(_SERVER))
     assert got.tolist() == want.tolist()
-    # numpy fallback backend: same rows, K sweeps instead of one
-    got_np = batch.reject_rates(_SERVER[:4], _POOL[:4], backend="numpy")
-    want_np = np.stack([e.reject_rates(_SERVER[:4], _POOL[:4],
-                                       backend="numpy")
-                        for e in engines])
+    # fractional input, the numpy sweep: same rows, K sweeps instead
+    # of one
+    frac = [replay_engine.CompiledReplay(v, shift_half(d), CFG)
+            for v, d in worlds]
+    got_np = replay_engine.CompiledReplayBatch(frac).reject_rates(
+        _SERVER[:4], _POOL[:4])
+    want_np = np.stack([e.reject_rates(_SERVER[:4], _POOL[:4])
+                        for e in frac])
     assert got_np.tolist() == want_np.tolist()
 
 

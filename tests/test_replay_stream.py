@@ -5,6 +5,7 @@ synthetic trace — plus the int16 state-packing equivalence rules."""
 import numpy as np
 import pytest
 
+from _fractional import shift_half
 from repro.core import cluster_sim, replay_engine, traces
 
 CFG = cluster_sim.ClusterConfig(n_servers=8, pool_sockets=8,
@@ -37,23 +38,35 @@ def test_stream_bit_exact_on_fixture_all_backends():
     stream = replay_engine.CompiledReplayStream(
         vms, dec, cfg, max_events_per_shard=256)
     assert stream.reject_rates(server, pool).tolist() == mono.tolist()
-    assert stream.reject_rates(server, pool,
-                               backend="numpy").tolist() == mono.tolist()
+    # the numpy sweeps, on fractional input, against the scalar oracle
+    shifted = shift_half(dec)
+    want = [cluster_sim.replay_reject_rate(vms, shifted, cfg, s, p)
+            for s, p in zip(server, pool)]
+    mono_np = replay_engine.CompiledReplay(vms, shifted, cfg)
+    stream_np = replay_engine.CompiledReplayStream(
+        vms, shifted, cfg, max_events_per_shard=256)
+    assert not (mono_np._exact or stream_np._exact)
+    assert mono_np.reject_rates(server, pool).tolist() == want
+    assert stream_np.reject_rates(server, pool).tolist() == want
 
 
 def test_stream_multi_shard_carry_matches_monolithic():
     vms, dec = _trace()
-    eng = replay_engine.CompiledReplay(vms, dec, CFG)
-    mono = eng.reject_rates(_SERVER, _POOL)
+    mono = replay_engine.CompiledReplay(vms, dec, CFG).reject_rates(
+        _SERVER, _POOL)
+    shifted = shift_half(dec)
+    mono_np = replay_engine.CompiledReplay(vms, shifted, CFG).reject_rates(
+        _SERVER, _POOL)
     for budget in (256, 320):           # aligned and ragged shard splits
         stream = replay_engine.CompiledReplayStream(
             vms, dec, CFG, max_events_per_shard=budget)
         assert stream.n_shards > 1      # the carry actually threads
         assert stream.reject_rates(_SERVER,
                                    _POOL).tolist() == mono.tolist()
-        assert stream.reject_rates(_SERVER, _POOL,
-                                   backend="numpy").tolist() \
-            == mono.tolist()
+        stream_np = replay_engine.CompiledReplayStream(
+            vms, shifted, CFG, max_events_per_shard=budget)
+        assert stream_np.reject_rates(_SERVER, _POOL).tolist() \
+            == mono_np.tolist()
 
 
 def test_stream_chunked_construction_matches_monolithic():
@@ -193,10 +206,8 @@ def test_int16_matches_int32_near_boundary():
     pool = np.array([safe - pay_p, 300.0, 0.0, safe - pay_p])
     assert eng._pick_state_dtype(np.floor(server),
                                  np.floor(pool)) == "int16"
-    i16 = eng.reject_rates(server, pool, backend="jax",
-                           state_dtype="int16")
-    i32 = eng.reject_rates(server, pool, backend="jax",
-                           state_dtype="int32")
+    i16 = eng.reject_rates(server, pool, state_dtype="int16")
+    i32 = eng.reject_rates(server, pool, state_dtype="int32")
     oracle = [cluster_sim.replay_reject_rate(vms, dec, CFG, s, p)
               for s, p in zip(server, pool)]
     assert i16.tolist() == i32.tolist() == oracle
@@ -223,9 +234,9 @@ def test_int16_matches_int32_near_boundary():
     # pool=0 lane: every placement falls back all-local, then every
     # migrate returns un-consumed pool — the deficit path int16 must
     # survive (carry goes negative by up to _mig_pool_sum)
-    m16 = eng_mig.reject_rates(server, pool, backend="jax",
+    m16 = eng_mig.reject_rates(server, pool,
                                state_dtype="int16")
-    m32 = eng_mig.reject_rates(server, pool, backend="jax",
+    m32 = eng_mig.reject_rates(server, pool,
                                state_dtype="int32")
     mig_oracle = [cluster_sim.replay_reject_rate(vms, mig_dec, CFG, s, p)
                   for s, p in zip(server, pool)]
@@ -236,16 +247,16 @@ def test_int16_matches_int32_near_boundary():
     assert st_mig._mig_pool_sum == eng_mig._mig_pool_sum
     assert st_mig._pick_state_dtype(np.floor(server),
                                     np.floor(pool)) == "int16"
-    assert st_mig.reject_rates(server, pool, backend="jax",
+    assert st_mig.reject_rates(server, pool,
                                state_dtype="int16").tolist() == mig_oracle
     # the stream shares the same rules
     stream = replay_engine.CompiledReplayStream(
         vms, dec, CFG, max_events_per_shard=256)
     assert stream._pick_state_dtype(np.floor(server),
                                     np.floor(pool)) == "int16"
-    s16 = stream.reject_rates(server, pool, backend="jax",
+    s16 = stream.reject_rates(server, pool,
                               state_dtype="int16")
-    s32 = stream.reject_rates(server, pool, backend="jax",
+    s32 = stream.reject_rates(server, pool,
                               state_dtype="int32")
     assert s16.tolist() == s32.tolist() == oracle
 
@@ -274,10 +285,8 @@ def test_int16_migrate_pool_deficit_boundary():
     assert eng._mig_pool_sum + eng._pay_pool_max == safe
     assert eng._pick_state_dtype(np.floor(server),
                                  np.floor(pool)) == "int16"
-    i16 = eng.reject_rates(server, pool, backend="jax",
-                           state_dtype="int16")
-    i32 = eng.reject_rates(server, pool, backend="jax",
-                           state_dtype="int32")
+    i16 = eng.reject_rates(server, pool, state_dtype="int16")
+    i32 = eng.reject_rates(server, pool, state_dtype="int32")
     oracle = [cluster_sim.replay_reject_rate(vms, dec, cfg, s, p)
               for s, p in zip(server, pool)]
     assert i16.tolist() == i32.tolist() == oracle
@@ -285,7 +294,7 @@ def test_int16_migrate_pool_deficit_boundary():
         vms, dec, cfg, max_events_per_shard=256)
     assert stream._pick_state_dtype(np.floor(server),
                                     np.floor(pool)) == "int16"
-    assert stream.reject_rates(server, pool, backend="jax",
+    assert stream.reject_rates(server, pool,
                                state_dtype="int16").tolist() == oracle
     # one more migrating VM crosses the bound -> automatic int32
     vms40, dec40 = build(40)
@@ -350,10 +359,11 @@ def test_stream_batch_bit_exact_vs_independent_streams():
     both backends and both forced state dtypes (the batched carry sweep
     reads the keyed jit cache, so int16 engages for batches too)."""
     vms, _ = _trace()
-    streams, singles = [], []
+    streams, singles, decs = [], [], []
     for frac in (0.10, 0.25, 0.40):       # K=3 decision seeds, one trace
         dec, _ = cluster_sim.policy_decisions(vms, "static",
                                               static_pool_frac=frac)
+        decs.append(dec)
         streams.append(replay_engine.CompiledReplayStream(
             vms, dec, CFG, max_events_per_shard=256))
         singles.append(streams[-1].reject_rates(_SERVER, _POOL))
@@ -361,8 +371,13 @@ def test_stream_batch_bit_exact_vs_independent_streams():
     assert batch.n_shards > 1
     want = np.stack(singles)
     assert batch.reject_rates(_SERVER, _POOL).tolist() == want.tolist()
-    assert batch.reject_rates(_SERVER, _POOL,
-                              backend="numpy").tolist() == want.tolist()
+    # fractional input: K numpy stream sweeps, row for row
+    shifted = [replay_engine.CompiledReplayStream(
+        vms, shift_half(s_dec), CFG, max_events_per_shard=256)
+        for s_dec in decs]
+    want_np = np.stack([s.reject_rates(_SERVER, _POOL) for s in shifted])
+    assert replay_engine.CompiledReplayStreamBatch(shifted).reject_rates(
+        _SERVER, _POOL).tolist() == want_np.tolist()
     # int16-eligible candidate block: forced packings agree bitwise
     srv16 = np.array([768.0, 200.0, 140.0, 60.0])
     pool16 = np.array([2048.0, 300.0, 0.0, 2048.0])
@@ -388,17 +403,23 @@ def test_stream_batch_fixture_and_memory_bound():
                                     gb_per_core=4.0)
     server = np.array([768.0, 120.0, 60.0, 30.0])
     pool = np.array([512.0, 64.0, 0.0, 512.0])
-    streams = []
+    streams, decs = [], []
     for frac in (0.15, 0.30):
         dec, _ = cluster_sim.policy_decisions(vms, "static",
                                               static_pool_frac=frac)
+        decs.append(dec)
         streams.append(replay_engine.CompiledReplayStream(
             vms, dec, cfg, max_events_per_shard=256))
     batch = replay_engine.CompiledReplayStreamBatch(streams)
     want = np.stack([s.reject_rates(server, pool) for s in streams])
     assert batch.reject_rates(server, pool).tolist() == want.tolist()
-    assert batch.reject_rates(server, pool,
-                              backend="numpy").tolist() == want.tolist()
+    # fractional input: the numpy shard sweeps, row for row
+    frac = [replay_engine.CompiledReplayStream(
+        vms, shift_half(s_dec), cfg, max_events_per_shard=256)
+        for s_dec in decs]
+    want_np = np.stack([s.reject_rates(server, pool) for s in frac])
+    assert replay_engine.CompiledReplayStreamBatch(frac).reject_rates(
+        server, pool).tolist() == want_np.tolist()
     # THE memory bound: one stacked shard batch of K rows, set by the
     # shard budget — not by total event count
     assert batch.shard_pad_events <= 256

@@ -162,8 +162,8 @@ def test_sharded_sweep_selects_pallas_on_tpu_mesh(topo):
 def test_lane_sharded_sweep_selects_pallas_on_tpu_mesh(topo, batched):
     """The candidate-lane plan on the 2x2 host: events replicated, the
     lanes split four ways, the Pallas kernel inside the ``shard_map`` —
-    one stream's carried shard sweep (``chip_smoke.py --chips 4``) and
-    the shared-init batch a search of K=2 traces runs on four chips."""
+    the unbatched carried shard sweep and the shared-init batch a search
+    of K=2 traces runs on four chips."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -200,3 +200,33 @@ def test_lane_sharded_sweep_selects_pallas_on_tpu_mesh(topo, batched):
     assert rej.shape == lead + (LANES,) and rej.dtype == jnp.int32
     assert any(key[-2:] == ("lane", "pallas")
                for key in sweep_core.jit_cache_keys())
+
+
+def test_single_stream_lane_plan_compiles(topo):
+    """What one stream runs with ``devices=4`` (``chip_smoke.py --chips
+    4``'s lane plan): the batched carry sweep of a K=1 stream batch, its
+    candidate lanes split four ways, events replicated."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        mesh = Mesh(np.array(topo.devices[:4]), ("shard",))
+        rep = NamedSharding(mesh, P())
+        lanes = NamedSharding(mesh, P(None, "shard"))
+        slots = NamedSharding(mesh, P(None, None, "shard"))
+
+        def s(shape, sh, d=jnp.int32):
+            return jax.ShapeDtypeStruct(shape, d, sharding=sh)
+        sweep = sweep_core.get_sweep("int32", with_carry=True, batched=True,
+                                     mesh=mesh, shard_axis="lane")
+        compiled = sweep.lower(
+            tuple(s((1, EVENTS), rep) for _ in range(6)),
+            s((SERVERS,), rep), s((1, LANES, SERVERS), lanes),
+            s((1, LANES, SERVERS), lanes), s((1, LANES, GROUPS), lanes),
+            s((1, SLOTS, LANES), slots), s((1, LANES), lanes),
+            s((1, LANES), lanes), s((1, LANES), lanes)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert "tpu_custom_call" in compiled.as_text()
+    rej = compiled.out_info[4]
+    assert rej.shape == (1, LANES) and rej.dtype == jnp.int32
